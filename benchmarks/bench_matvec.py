@@ -35,7 +35,7 @@ from repro.core.precond import DEFAULT_NYSTROM_RANK
 from repro.core.operator import default_table_size
 from repro.core.wlsh import build_exact_index, exact_kernel_matrix, exact_matvec
 
-from .common import emit, time_fn
+from .common import emit, refuse_on_tpu, time_fn
 
 # dense comparison: build the true kernel matrix where the O(m n^2) featurized
 # build fits in memory; above that use a random (n, n) proxy — the matvec cost
@@ -292,7 +292,9 @@ def distributed_rows(ns=DIST_NS, shard_counts=DIST_SHARDS,
     (step(K iters) - step(0 iters)) / K so featurize/index/routing builds
     cancel.  Reference backend — interpret-mode Pallas timings are
     meaningless, and the collectives are the thing being recorded.  A
-    failed shard count yields an explicit {"shards", "error"} marker row."""
+    failed shard count yields an explicit {"shards", "error"} marker row.
+    Refused on a TPU host (``refuse_on_tpu``)."""
+    refuse_on_tpu("bench_matvec.distributed_rows")
     root = pathlib.Path(__file__).resolve().parent.parent
     env = {"PYTHONPATH": str(root / "src"), "JAX_PLATFORMS": "cpu",
            "PATH": os.environ.get("PATH", "/usr/bin:/bin")}

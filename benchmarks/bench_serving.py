@@ -53,7 +53,7 @@ from repro import obs
 from repro.serve import Predictor, bucket_sizes
 from repro.serve.batcher import percentile
 
-from .common import emit
+from .common import emit, refuse_on_tpu
 
 # serving-scale model: m matches the quickstart fit; n only shapes the tables
 MODEL_N = 2048
@@ -302,7 +302,9 @@ def sharded_section(*, mesh=SHARDED_MESH, iters: int = 100,
     ``ratio_vs_single`` is the <=3x acceptance pin.  dedup=False broadcast
     wire (the ShardedPredictor interactive default).  Failure yields an
     explicit {"error": ...} marker instead of raising: a runner that cannot
-    spawn fake devices says nothing about the code."""
+    spawn fake devices says nothing about the code.  Refused on a TPU host
+    (``refuse_on_tpu``)."""
+    refuse_on_tpu("bench_serving.sharded_section")
     root = pathlib.Path(__file__).resolve().parent.parent
     need = mesh[0] * mesh[1]
     env = {"PYTHONPATH": str(root / "src"), "JAX_PLATFORMS": "cpu",

@@ -1,4 +1,5 @@
-"""Shared benchmark utilities: wall-clock timing + CSV emission.
+"""Shared benchmark utilities: wall-clock timing + CSV emission, and the
+guard of the fake-CPU-mesh sections.
 
 Timing goes through ``repro.obs`` spans so every benchmark sample also lands
 in the span buffers and the ``bench_us`` histogram — the benchmarks and the
@@ -45,3 +46,18 @@ def time_fn(fn: Callable, *args, warmup: int = 1, iters: int = 3,
 
 def emit(name: str, seconds: float, derived: str = "") -> None:
     print(f"{name},{seconds * 1e6:.1f},{derived}")
+
+
+def refuse_on_tpu(section: str) -> None:
+    """Refuse code that runs fake-CPU meshes in child processes.
+
+    On a TPU host this process already holds the chip, and the children are
+    forced onto the CPU: they would run and time the CPU and say nothing
+    about the device.  ``chip_smoke.py --chips 4`` runs the sharded paths
+    on chips."""
+    if jax.devices()[0].platform == "tpu":
+        raise RuntimeError(
+            f"{section} runs fake-CPU meshes in child processes, which on a "
+            f"TPU host would run on the CPU; run it on a CPU host "
+            f"(JAX_PLATFORMS=cpu), and `python chip_smoke.py --chips 4` for "
+            f"the sharded paths on the chip")

@@ -1,10 +1,7 @@
-"""JAX version shims.
+"""Mesh and shard_map construction, in one place.
 
-The codebase targets modern JAX (``jax.shard_map``, ``jax.sharding.AxisType``,
-``check_vma``), but CI containers may pin 0.4.x where shard_map still lives in
-``jax.experimental.shard_map`` with the ``check_rep`` keyword and meshes have
-no axis_types.  All mesh/shard_map construction goes through these two
-helpers so the rest of the code is version-agnostic.
+Every mesh in the repo has Auto axis types and every shard_map body runs
+with replication (VMA) checking off: the step functions psum explicitly.
 """
 from __future__ import annotations
 
@@ -12,22 +9,13 @@ import jax
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication/VMA checking off, any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """jax.shard_map with replication/VMA checking off."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    if not hasattr(jax, "make_mesh"):        # jax < 0.4.35
-        from jax.experimental import mesh_utils
-        return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=axis_types)
+def make_mesh(shape, axes, *, devices=None):
+    """jax.make_mesh with Auto axis types, over ``devices`` (default: all)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -35,7 +35,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import obs
-from ..backend import default_interpret, resolve_backend
+from ..backend import platform_of, resolve_backend, resolve_interpret
 from ..compat import shard_map
 from ..errors import SolveDivergedError, WireOverflowError
 from ..testing.faults import FaultPlan, apply_wire_fault, maybe_stall
@@ -71,14 +71,17 @@ class KRRStepConfig(NamedTuple):
 
 
 def _shard_operator(cfg: KRRStepConfig, f: BucketFn, lsh_local: LSHParams,
-                    *, fused: bool | None = None) -> WLSHOperator:
-    """Per-shard operator over the local LSH slice (backend resolved at
-    trace time — shard_map bodies must see a concrete choice).  ``fused``
-    overrides cfg.fused: a data-sharded step passes False so a blocked
-    index is built with the split kernels' geometry, not the fused one's."""
+                    mesh: Mesh, *, fused: bool | None = None) -> WLSHOperator:
+    """Per-shard operator over the local LSH slice.  Backend and interpret
+    mode follow the platform of the mesh's devices (shard_map bodies must
+    see a concrete choice): a step compiled for TPU devices gets compiled
+    kernels whatever the host's default backend.  ``fused`` overrides
+    cfg.fused: a data-sharded step passes False so its matvec takes the
+    split (psum-able) kernels."""
+    platform = platform_of(mesh)
     return WLSHOperator(lsh=lsh_local, bucket=f, table_size=cfg.table_size,
-                        backend=resolve_backend(cfg.backend),
-                        interpret=default_interpret(),
+                        backend=resolve_backend(cfg.backend, platform),
+                        interpret=resolve_interpret(None, platform),
                         fused=cfg.fused if fused is None else fused)
 
 
@@ -266,7 +269,8 @@ def make_krr_step(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     # scatter/gather still follow the slot-blocked visit lists when the
     # index carries the layout — only the reference split path ignores it
     want_blocked = local_fused or (
-        cfg.blocked_split and resolve_backend(cfg.backend) == "pallas")
+        cfg.blocked_split
+        and resolve_backend(cfg.backend, platform_of(mesh)) == "pallas")
     if cfg.precond == "nystrom" and n_data != 1:
         raise ValueError(
             "precond='nystrom' needs unsharded data axes (its pivot columns "
@@ -275,7 +279,7 @@ def make_krr_step(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def step(x_local, y_local, lsh_local):
-        op = _shard_operator(cfg, f, lsh_local, fused=local_fused)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=local_fused)
         idx = op.build_index(op.featurize(x_local), blocked=want_blocked)
         mv = make_distributed_matvec(cfg, op, n_data_shards=n_data)
         pre = _shard_preconditioner(cfg, mv, idx)
@@ -301,7 +305,7 @@ def make_krr_predict(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     n_data = _data_shard_count(mesh, cfg)
     local_fused = cfg.fused and n_data == 1
     want_blocked = (local_fused or cfg.blocked_split) and \
-        resolve_backend(cfg.backend) == "pallas"
+        resolve_backend(cfg.backend, platform_of(mesh)) == "pallas"
     in_specs = (P(cfg.data_axes, None),
                 LSHParams(w=P(cfg.model_axis, None), z=P(cfg.model_axis, None),
                           r1=P(cfg.model_axis, None), r2=P(cfg.model_axis, None)),
@@ -311,7 +315,7 @@ def make_krr_predict(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def predict(x_local, lsh_local, tables_local):
-        op = _shard_operator(cfg, f, lsh_local, fused=local_fused)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=local_fused)
         idx = op.build_index(op.featurize(x_local), blocked=want_blocked)
         out = op.readout(idx, tables_local, average=False)
         return jax.lax.psum(out, cfg.model_axis) / cfg.m
@@ -845,7 +849,7 @@ def make_krr_step_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
     if cfg.table_size % n_shards:
         raise ValueError("hash-join needs table_size divisible by the data "
                          f"shard count ({cfg.table_size} % {n_shards})")
-    backend = resolve_backend(cfg.backend)
+    backend = resolve_backend(cfg.backend, platform_of(mesh))
     use_kernels = backend == "pallas"
     data_spec = P(cfg.data_axes)
     in_specs = (P(cfg.data_axes, None), data_spec,
@@ -859,7 +863,7 @@ def make_krr_step_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
                        out_specs=out_specs)
     def step(x_local, y_local, lsh_local):
         maybe_stall(cfg.fault_plan, cfg.data_axes)
-        op = _shard_operator(cfg, f, lsh_local, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
         # blocked=True rides the layout's stable slot sort — the ONLY sort
         # in the step; parts='both' adds the route-kernel arrays on pallas
         idx = op.build_index(op.featurize(x_local), blocked=True,
@@ -878,10 +882,9 @@ def make_krr_step_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
             "(route + serve exchanges)").set(
             2 * n_shards * rt.cap * k_cols
             * jnp.dtype(payload_dtype).itemsize)
-        interp = default_interpret()
         mv = lambda v: _hashjoin_matvec(rt, lay, idx.coeff, cfg.m,
                                         cfg.data_axes, cfg.model_axis, v,
-                                        payload_dtype, interp,
+                                        payload_dtype, op.interpret,
                                         cfg.fault_plan)
         pre = _shard_preconditioner(cfg, None, idx)
         beta_local, resnorm = cg_iterations(mv, y_local, cfg,
@@ -889,7 +892,7 @@ def make_krr_step_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
         # final sharded prediction table for the solved beta (f32 wire)
         table, wire_nf = _hashjoin_loads(rt, lay, idx.coeff, beta_local,
                                          cfg.data_axes, m_loc, jnp.float32,
-                                         interp, cfg.fault_plan)
+                                         op.interpret, cfg.fault_plan)
         stats = StepStats(
             overflow_dropped=jax.lax.psum(rt.dropped, all_axes),
             wire_nonfinite=jax.lax.psum(wire_nf, all_axes))
@@ -974,7 +977,7 @@ def make_krr_predict_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
     if cfg.table_size % n_shards:
         raise ValueError("hash-join needs table_size divisible by the data "
                          f"shard count ({cfg.table_size} % {n_shards})")
-    backend = resolve_backend(cfg.backend)
+    backend = resolve_backend(cfg.backend, platform_of(mesh))
     use_kernels = backend == "pallas"
     in_specs = (P(cfg.data_axes, None),
                 LSHParams(w=P(cfg.model_axis, None), z=P(cfg.model_axis, None),
@@ -986,7 +989,7 @@ def make_krr_predict_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def predict(x_local, lsh_local, table_local):
-        op = _shard_operator(cfg, f, lsh_local, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
         # flatten my (m_loc, spp[, k]) slice to the served id space
         table_flat = table_local.reshape((-1,) + table_local.shape[2:])
         if not dedup:
@@ -1004,7 +1007,7 @@ def make_krr_predict_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
                             cfg.data_axes, cap_factor, kernels=use_kernels)
         out = _hashjoin_readout(rt, idx.blocked, idx.coeff, table_flat,
                                 cfg.data_axes, cfg.model_axis, cfg.m,
-                                payload_dtype, default_interpret(),
+                                payload_dtype, op.interpret,
                                 plan=cfg.fault_plan)
         if with_stats:
             # dropped is per (model, data) shard; the model psum leaves one

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..backend import platform_of
 from ..errors import NonFiniteError, SolveDivergedError
 from .bucket_fns import get_bucket_fn
 from .kernels import WLSHKernelSpec
@@ -294,11 +295,13 @@ class WLSHKRRModel(NamedTuple):
 def model_operator(model: WLSHKRRModel, *,
                    backend: str | None = None) -> WLSHOperator:
     """Rebuild the operator a fitted model was trained with (optionally
-    overriding the backend — all backends read the same tables)."""
+    overriding the backend — all backends read the same tables), for the
+    platform its tables live on."""
     return make_operator(model.lsh, get_bucket_fn(model.bucket_name),
                          model.table_size,
                          backend=backend if backend is not None
-                         else model.backend)
+                         else model.backend,
+                         platform=platform_of(model.tables))
 
 
 def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
@@ -361,7 +364,7 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
         table_size = default_table_size(n)
     lsh = sample_lsh_params(key, m, d, spec.pdf, spec.lengthscale)
     op = make_operator(lsh, get_bucket_fn(spec.bucket.name), table_size,
-                       backend=backend, fused=fused)
+                       backend=backend, fused=fused, platform=platform_of(x))
     with obs.span("fit.featurize", {"n": n, "m": m},
                   to_histogram=obs.histogram(
                       "fit_featurize_us", "featurize wall time per fit")):

@@ -19,9 +19,12 @@ Every primitive dispatches on ``backend``:
 
 * ``reference`` — the pure-jnp path (core/lsh.py + core/wlsh.py).
 * ``pallas``    — the fused kernels (kernels/featurize + kernels/binning),
-  with interpret mode auto-selected from the platform and all shape padding
+  compiled on a TPU and interpreted on the CPU, with all shape padding
   handled internally.
 * ``auto``      — resolved per platform at construction (see repro.backend).
+
+The platform is the one the program is placed on: ``make_operator`` takes
+it from the caller (the device of the data, or a mesh's devices).
 
 The solver (core/krr.py), the distributed step (core/distributed.py) and the
 benchmarks all talk to this interface only, so swapping kernels or meshes is
@@ -37,11 +40,10 @@ from typing import NamedTuple, Union
 import jax
 import jax.numpy as jnp
 
-from ..backend import default_interpret, resolve_backend
+from ..backend import platform_of, resolve_backend, resolve_interpret
 from .bucket_fns import BucketFn
 from .lsh import Features, LSHParams, featurize as featurize_reference
-from .wlsh import (BLOCKED_N, BLOCKED_SPLIT_N, BLOCKED_SPLIT_T, BLOCKED_T,
-                   ExactIndex, TableIndex, build_blocked_layout,
+from .wlsh import (ExactIndex, TableIndex, build_blocked_layout,
                    build_exact_index, build_table_index, exact_matvec,
                    table_loads, table_matvec_fused, table_readout)
 
@@ -60,14 +62,16 @@ class WLSHOperator(NamedTuple):
 
     A NamedTuple so it can be built inside jit/shard_map from traced local
     LSH shards and closed over freely; ``backend`` must already be concrete
-    ('reference' or 'pallas') — use ``make_operator`` to resolve 'auto'.
+    ('reference' or 'pallas') — use ``make_operator`` to resolve 'auto' and
+    to choose ``interpret`` for the platform.
     """
 
     lsh: LSHParams
     bucket: BucketFn
     table_size: int
     backend: str = "reference"
-    interpret: bool = True       # Pallas interpret mode (ignored by reference)
+    interpret: bool = False      # Pallas interpreter, CPU only (ignored by
+                                 # reference)
     fused: bool = True           # one-pass matvec off the slot-blocked layout
 
     # -- featurization ------------------------------------------------------
@@ -105,16 +109,9 @@ class WLSHOperator(NamedTuple):
             want_blocked = self.fused if blocked is None else blocked
             if want_blocked:
                 # only materialize the array group this backend's fused
-                # matvec consumes (the groups are disjoint and O(mn)-sized).
-                # A pallas layout destined for the split kernels (operator
-                # not fused — e.g. the data-sharded psum path) takes the
-                # split-tuned geometry; the fused kernel keeps its own.
-                split_only = self.backend == "pallas" and not self.fused
-                bn = BLOCKED_SPLIT_N if split_only else BLOCKED_N
-                bt = BLOCKED_SPLIT_T if split_only else BLOCKED_T
+                # matvec consumes (the groups are disjoint and O(mn)-sized)
                 idx = idx._replace(blocked=build_blocked_layout(
                     idx.slot, idx.coeff, self.table_size,
-                    block_n=bn, block_t=bt,
                     parts=self.backend if parts is None else parts))
             return idx
         if mode == "exact":
@@ -228,13 +225,16 @@ class WLSHOperator(NamedTuple):
 def make_operator(lsh: LSHParams, bucket: BucketFn, table_size: int, *,
                   backend: str | None = "auto",
                   interpret: bool | None = None,
-                  fused: bool = True) -> WLSHOperator:
-    """Construct an operator with 'auto' backend/interpret resolved for this
-    platform (the only place resolution happens — everything downstream sees
-    a concrete backend).  ``fused=False`` keeps the split scatter→gather
-    matvec reachable for A/B runs."""
+                  fused: bool = True,
+                  platform: str | None = None) -> WLSHOperator:
+    """Construct an operator with 'auto' backend and interpret mode resolved
+    for ``platform`` — the platform the program is placed on (default: the
+    default device's).  Everything downstream sees a concrete backend.
+    ``interpret=None`` interprets the kernels exactly off the TPU; asking
+    for the interpreter on a TPU raises.  ``fused=False`` keeps the split
+    scatter→gather matvec reachable for A/B runs."""
+    platform = platform_of() if platform is None else platform
     return WLSHOperator(lsh=lsh, bucket=bucket, table_size=int(table_size),
-                        backend=resolve_backend(backend),
-                        interpret=default_interpret() if interpret is None
-                        else interpret,
+                        backend=resolve_backend(backend, platform),
+                        interpret=resolve_interpret(interpret, platform),
                         fused=fused)

@@ -90,20 +90,13 @@ def exact_kernel_matrix(feats: Features) -> Array:
 # table (CountSketch) mode
 # ---------------------------------------------------------------------------
 
-# Default fused-kernel geometry: one point block of the sorted layout and one
-# table tile.  bn = 128 keeps tile-capacity padding small (a nonempty tile
-# wastes at most bn-1 layout slots); bt = 512 matches the split kernels.
+# Layout geometry of the visit-list kernels (fused and split alike): one
+# point block of the sorted layout and one table tile.  bn = 128 is the
+# narrowest lane-dense block a TPU kernel accepts and keeps tile-capacity
+# padding small (a nonempty tile wastes at most bn-1 layout slots); bt = 512
+# matches the cross-product kernels.
 BLOCKED_N = 128
 BLOCKED_T = 512
-
-# Default geometry when the layout feeds the *split* visit-list kernels
-# (distributed psum path).  Their per-step cost is dominated by the one-hot
-# materialization plus an HBM table-tile round trip per visit, so a narrower
-# point block wins on CPU/interpret (measured 4.3x vs 2.6x at bn=128 over
-# the cross-product split, n=1024).  bn = 64 is half an MXU contraction —
-# on-device retuning rides the ROADMAP "TPU validation" item.
-BLOCKED_SPLIT_N = 64
-BLOCKED_SPLIT_T = 512
 
 
 class BlockedLayout(NamedTuple):

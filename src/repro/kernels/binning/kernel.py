@@ -1,22 +1,29 @@
-"""Pallas TPU kernels: CountSketch bucket scatter/gather as one-hot MXU matmuls.
+"""Pallas TPU kernels: CountSketch bucket scatter/gather as one-hot reductions.
 
 TPUs have no scatter atomics; the paper's bucket-load accumulation
-(B_j += beta_i * weight_i) is re-expressed as a systolic matmul:
+(B_j += beta_i * weight_i) is re-expressed against a one-hot mask of one
+point block against one table tile,
 
-    table_tile (1, BT) += contrib_block (1, BN) @ onehot(slot - tile_lo) (BN, BT)
+    hit (BT, BN) = iota_rows == slot_block - tile_lo
+    table_tile (BT,) += sum over lanes of where(hit, contrib_block, 0)
 
-and the readout gather (out_i = table[slot_i]) as the transposed product.
-The one-hot matrices never touch HBM — they are built in VMEM per grid step
-from an iota compare.  Grid iterates the reduction dimension (point blocks for
-scatter, table tiles for gather) in the trailing, sequential position so the
-output tile accumulates in place across steps (standard Pallas revisiting
-pattern).
+and the readout gather (out_i = table[slot_i]) as the transposed reduction,
+sum over sublanes of where(hit, table_tile, 0).  The masks never touch HBM —
+they are built in VMEM per grid step from an iota compare.  Both reductions
+run on the VPU in f32: a gather selects exactly one value per point, so it
+is exact, and a scatter adds the same f32 terms as the reference scatter-add
+in a different order.  (As one-hot matmuls these products have 1..k rows,
+so the MXU would spend its time loading the mask as weights, and an f32
+product there takes several bf16 passes.)  Grid iterates the reduction
+dimension (point blocks for scatter, table tiles for gather) in the
+trailing, sequential position so the output tile accumulates in place
+across steps (standard Pallas revisiting pattern).
 
 Two kernel families:
 
 * **split** (``bin_scatter_pallas`` / ``bin_gather_pallas``) — iterate the
   full (point-block × table-tile) cross product and materialize the (m, B)
-  table in HBM between the two calls.  O(n·B) MXU work, but the table is a
+  table in HBM between the two calls.  O(n·B) mask work, but the table is a
   psum-able array — this is what the distributed data-shard merge needs.
 * **fused** (``bin_fused_matvec_pallas``) — one ``pallas_call`` drives both
   products off a slot-blocked layout (``core.wlsh.BlockedLayout``): points
@@ -35,8 +42,26 @@ Two kernel families:
   every tile at least once (empty tiles against an all-padding block), so
   the HBM output table is explicitly zeroed tile by tile — no tile is left
   uninitialized by the data-dependent grid.  Multi-RHS is native: the k
-  columns share each one-hot via (k, bn)×(bn, bt) products against
-  (1, k, bt) table blocks.
+  columns share each mask against (1, k, bt) table blocks.
+
+TPU layout: a block's last two dims must be multiples of (8, 128) or span
+the array, so every per-point or per-slot array enters a kernel with an
+explicit row axis — (m, 1, X) for one RHS or per-instance data, (m, k, X)
+for a k-column block — and each grid step sees lane-dense (rows, width)
+tiles.  The mask is (bt, bn): table slots on sublanes, points on lanes, so
+a point block's row broadcasts down it and a scatter lands as (bt, k)
+columns.  The fused kernel keeps its VMEM table tile in that column form;
+the kernels whose tables live in HBM as lane-dense rows transpose one
+(k, bt) tile per visit.
+
+Scalar-prefetched visit lists live in SMEM, which holds 1 MiB on v5e.  A
+per-instance schedule larger than ``SMEM_SCHEDULE_BYTES`` runs as several
+calls over instance groups (``_grouped_call``), all writing one output
+buffer aliased from call to call; the flat route-pack schedule runs in
+consecutive chunks, each resuming the tile the previous chunk left open.
+
+``interpret`` (required on every entry point) runs a kernel in the Pallas
+interpreter instead of compiling it — the CPU test path only.
 """
 from __future__ import annotations
 
@@ -50,23 +75,110 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_N = 1024       # points per block
 BLOCK_T = 512        # table slots per tile
 
+# Scalar-prefetched schedule bytes per kernel call: half of v5e's 1 MiB SMEM.
+SMEM_SCHEDULE_BYTES = 512 * 1024
+
+
+def _with_row_axis(a):
+    """(m, X) -> (m, 1, X); (m, k, X) passes through."""
+    return a[:, None, :] if a.ndim == 2 else a
+
+
+def _hit(slot_ref, tile, bt):
+    """(bt, bn) mask of this block's (1, bn) slot row against table tile
+    ``tile``: column p is set at row slot[p] - tile·bt, and empty when the
+    slot lies outside the tile."""
+    slot = slot_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bt, slot.shape[-1]), 0)
+    return row == slot - tile * bt
+
+
+def _scatter(hit, contrib):
+    """(k, bn) per-point contributions -> k (bt, 1) tile-load columns."""
+    return [jnp.sum(jnp.where(hit, contrib[c:c + 1], 0.0), axis=1,
+                    keepdims=True) for c in range(contrib.shape[0])]
+
+
+def _gather(hit, cols):
+    """k (bt, 1) table columns -> (k, bn) per-point reads."""
+    rows = [jnp.sum(jnp.where(hit, col, 0.0), axis=0, keepdims=True)
+            for col in cols]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
+
+def _cols_to_rows(cols):
+    """k (bt, 1) columns -> (k, bt) lane-dense rows (each column widened to
+    the 8 lanes of a transposable tile)."""
+    rows = [jnp.broadcast_to(c, (c.shape[0], 8)).T[0:1] for c in cols]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
+
+def _rows_to_cols(rows):
+    """(k, bt) lane-dense rows -> k (bt, 1) columns."""
+    return [jnp.broadcast_to(rows[c:c + 1], (8, rows.shape[1])).T[:, 0:1]
+            for c in range(rows.shape[0])]
+
+
+def _instance_groups(m: int, per_instance_bytes: int):
+    """Static (start, size) instance groups whose schedules fit the SMEM
+    budget of one call."""
+    g = SMEM_SCHEDULE_BYTES // per_instance_bytes
+    if g < 1:
+        raise ValueError(
+            f"one instance's visit schedule needs {per_instance_bytes} bytes "
+            f"of SMEM, over the {SMEM_SCHEDULE_BYTES}-byte budget of one "
+            f"call.  A schedule grows with n/block_n + table_size/block_t "
+            f"(the fused matvec at table_size = default_table_size(n) fits "
+            f"up to n = 2^20 points per device); fewer points or table "
+            f"slots per device fit, and chunking the visit axis is not "
+            f"implemented")
+    return [(s, min(g, m - s)) for s in range(0, m, g)]
+
+
+def _grouped_call(body, sched, operands, specs, out_shape, *, interpret,
+                  scratch_shapes=()):
+    """Run a per-instance visit-list kernel over the grid (m, V).
+
+    ``sched`` are (m, V) int32 schedules, scalar-prefetched.  ``specs(s)``
+    returns (in_specs, out_spec) for the group starting at instance s: the
+    index maps see the group-local instance i (which indexes the schedule
+    rows) and address instance i + s of the operands.  One pallas_call runs
+    per group; each writes its instances' rows of the single output buffer,
+    which later calls take as an aliased input the body never reads."""
+    m, n_vis = sched[0].shape
+    out = None
+    for s, g in _instance_groups(m, 4 * len(sched) * n_vis):
+        in_specs, out_spec = specs(s)
+        args = [a[s:s + g] for a in sched] + list(operands)
+        kernel, aliases = body, {}
+        if out is not None:
+            skip = len(args)
+            in_specs = in_specs + [pl.BlockSpec(memory_space=pl.ANY)]
+            args.append(out)
+            aliases = {skip: 0}
+
+            def kernel(*refs, skip=skip):
+                body(*refs[:skip], *refs[skip + 1:])
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(sched), grid=(g, n_vis),
+                in_specs=in_specs, out_specs=out_spec,
+                scratch_shapes=list(scratch_shapes)),
+            out_shape=out_shape,
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(*args)
+    return out
+
 
 def _scatter_body(slot_ref, contrib_ref, table_ref):
-    nb = pl.program_id(2)
-
-    @pl.when(nb == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         table_ref[...] = jnp.zeros_like(table_ref)
 
-    bt = table_ref.shape[1]
-    tile_lo = pl.program_id(1) * bt
-    slot = slot_ref[...][0]                                  # (bn,) int32
-    contrib = contrib_ref[...]                               # (1, bn) f32
-    col = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], bt), 1)
-    onehot = (slot[:, None] - tile_lo == col).astype(jnp.float32)
-    table_ref[...] += jax.lax.dot_general(
-        contrib, onehot, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    hit = _hit(slot_ref, pl.program_id(1), table_ref.shape[-1])
+    table_ref[...] += _cols_to_rows(_scatter(hit, contrib_ref[...]))
 
 
 def _gather_body(slot_ref, table_ref, out_ref):
@@ -76,20 +188,13 @@ def _gather_body(slot_ref, table_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bt = table_ref.shape[1]
-    tile_lo = tb * bt
-    slot = slot_ref[...][0]                                  # (bn,)
-    col = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], bt), 1)
-    onehot = (slot[:, None] - tile_lo == col).astype(jnp.float32)
-    # out (1, bn) += table (1, bt) @ onehot^T (bt, bn)
-    out_ref[...] += jax.lax.dot_general(
-        table_ref[...], onehot, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    hit = _hit(slot_ref, tb, table_ref.shape[-1])
+    out_ref[...] += _gather(hit, _rows_to_cols(table_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("table_size", "interpret",
                                              "block_n", "block_t"))
-def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool = True,
+def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool,
                        block_n: int = BLOCK_N, block_t: int = BLOCK_T):
     """slot (m, n) int32 in [0, table_size); contrib (m, n) f32.
     Returns tables (m, table_size) f32 with tables[s, j] = sum_{slot==j} contrib."""
@@ -97,16 +202,15 @@ def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool = True
     bn, bt = min(block_n, n), min(block_t, table_size)
     if n % bn or table_size % bt:
         raise ValueError("n and table_size must divide their block sizes")
-    grid = (m, table_size // bt, n // bn)
+    point_spec = pl.BlockSpec((None, 1, bn), lambda i, t, j: (i, 0, j))
     return pl.pallas_call(
         _scatter_body,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bn), lambda i, t, j: (i, j)),
-                  pl.BlockSpec((1, bn), lambda i, t, j: (i, j))],
-        out_specs=pl.BlockSpec((1, bt), lambda i, t, j: (i, t)),
-        out_shape=jax.ShapeDtypeStruct((m, table_size), jnp.float32),
+        grid=(m, table_size // bt, n // bn),
+        in_specs=[point_spec, point_spec],
+        out_specs=pl.BlockSpec((None, 1, bt), lambda i, t, j: (i, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((m, 1, table_size), jnp.float32),
         interpret=interpret,
-    )(slot, contrib)
+    )(_with_row_axis(slot), _with_row_axis(contrib.astype(jnp.float32)))[:, 0]
 
 
 def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
@@ -114,79 +218,39 @@ def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
     """One visit: (point block, table tile, phase) from the prefetched lists.
 
     Tiles arrive in ascending order with all scatter visits before any gather
-    visit, so ``table_ref`` (VMEM scratch) is zeroed exactly once per tile,
-    accumulated over the tile's scatter visits, and then read by its gather
-    visits — it never round-trips through HBM.  Padding visits re-gather the
-    last real block against the unchanged tile (idempotent full overwrite).
+    visit, so ``table_ref`` (VMEM scratch, (bt, k) columns) is zeroed
+    exactly once per tile, accumulated over the tile's scatter visits, and
+    then read by its gather visits — it never round-trips through HBM.
+    Padding visits re-gather the last real block against the unchanged tile
+    (idempotent full overwrite).  The k RHS columns share every mask.
     """
     i, j = pl.program_id(0), pl.program_id(1)
     tile = v_tile_ref[i, j]
     phase = v_phase_ref[i, j]
     prev_tile = v_tile_ref[i, jnp.maximum(j - 1, 0)]
-    new_tile = (j == 0) | (tile != prev_tile)
 
-    @pl.when(new_tile)
+    @pl.when((j == 0) | (tile != prev_tile))
     def _zero():
         table_ref[...] = jnp.zeros_like(table_ref)
 
-    bt = table_ref.shape[1]
-    slot = slot_ref[...][0]                                  # (bn,) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], bt), 1)
-    onehot = (slot[:, None] - tile * bt == col).astype(jnp.float32)
+    hit = _hit(slot_ref, tile, table_ref.shape[0])
 
     @pl.when(phase == 0)
-    def _scatter():
-        contrib = coeff_ref[...] * beta_ref[...]             # (1, bn)
-        table_ref[...] += jax.lax.dot_general(
-            contrib, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _scatter_visit():
+        cols = _scatter(hit, coeff_ref[...] * beta_ref[...])
+        for c, col in enumerate(cols):
+            table_ref[:, c:c + 1] += col
 
     @pl.when(phase == 1)
-    def _gather():
-        out_ref[...] = coeff_ref[...] * jax.lax.dot_general(
-            table_ref[...], onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-
-def _fused_body_mrhs(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
-                     beta_ref, out_ref, table_ref):
-    """Multi-RHS variant of ``_fused_body``: the k RHS columns share every
-    one-hot matrix, so the tile products widen from (1, bn)×(bn, bt) to
-    (k, bn)×(bn, bt) and the VMEM table tile to (k, bt) — same visit
-    schedule, same HBM traffic for slots/coeffs, k× the MXU work."""
-    i, j = pl.program_id(0), pl.program_id(1)
-    tile = v_tile_ref[i, j]
-    phase = v_phase_ref[i, j]
-    prev_tile = v_tile_ref[i, jnp.maximum(j - 1, 0)]
-    new_tile = (j == 0) | (tile != prev_tile)
-
-    @pl.when(new_tile)
-    def _zero():
-        table_ref[...] = jnp.zeros_like(table_ref)
-
-    bt = table_ref.shape[1]
-    slot = slot_ref[...][0]                                  # (bn,) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], bt), 1)
-    onehot = (slot[:, None] - tile * bt == col).astype(jnp.float32)
-
-    @pl.when(phase == 0)
-    def _scatter():
-        contrib = coeff_ref[...] * beta_ref[...][0]          # (k, bn)
-        table_ref[...] += jax.lax.dot_general(
-            contrib, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(phase == 1)
-    def _gather():
-        out_ref[...] = (coeff_ref[...] * jax.lax.dot_general(
-            table_ref[...], onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32))[None]
+    def _gather_visit():
+        cols = [table_ref[:, c:c + 1] for c in range(table_ref.shape[1])]
+        out_ref[...] = coeff_ref[...] * _gather(hit, cols)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
 def bin_fused_matvec_pallas(v_block, v_tile, v_phase, slot_lay, coeff_lay,
                             beta_lay, *, block_n: int, block_t: int,
-                            interpret: bool = True):
+                            interpret: bool):
     """Fused scatter→gather over a slot-blocked layout (one kernel call).
 
     v_block/v_tile/v_phase (m, V) int32 — the per-instance visit schedule
@@ -197,48 +261,33 @@ def bin_fused_matvec_pallas(v_block, v_tile, v_phase, slot_lay, coeff_lay,
     Returns out_lay of ``beta_lay``'s shape, f32, with
     ``out_lay[..., p] = coeff_lay[p] * table[slot_lay[p]]`` at every real
     layout position (padding positions have coeff 0).  The (m, B[, k]) table
-    exists only as a (1|k, block_t) VMEM scratch tile — the k columns ride
-    the same one-hot products, so the extra HBM traffic over single-RHS is
-    just beta/out themselves.
+    exists only as a (block_t, 1|k) VMEM scratch tile — the k columns ride
+    the same masks, so the extra HBM traffic over single-RHS is just
+    beta/out themselves.
     """
     m, layout_len = slot_lay.shape
     if layout_len % block_n:
         raise ValueError("layout length must be a multiple of block_n")
-    n_vis = v_block.shape[1]
-    lay_spec = pl.BlockSpec((1, block_n), lambda i, j, vb, vt, vp: (i, vb[i, j]))
-    if beta_lay.ndim == 2:
-        beta_spec, scratch_rows = lay_spec, 1
-        body = _fused_body
-    else:
-        k = beta_lay.shape[1]
-        beta_spec = pl.BlockSpec((1, k, block_n),
-                                 lambda i, j, vb, vt, vp: (i, 0, vb[i, j]))
-        scratch_rows, body = k, _fused_body_mrhs
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(m, n_vis),
-        in_specs=[lay_spec, lay_spec, beta_spec],
-        out_specs=beta_spec,
-        scratch_shapes=[pltpu.VMEM((scratch_rows, block_t), jnp.float32)],
-    )
-    return pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(beta_lay.shape, jnp.float32),
-        interpret=interpret,
-    )(v_block, v_tile, v_phase, slot_lay, coeff_lay, beta_lay)
+    beta3 = _with_row_axis(beta_lay.astype(jnp.float32))
+    k = beta3.shape[1]
 
+    def specs(s):
+        def index(i, j, vb, vt, vp):
+            return i + s, 0, vb[i, j]
+        lay_spec = pl.BlockSpec((None, 1, block_n), index)
+        beta_spec = pl.BlockSpec((None, k, block_n), index)
+        return [lay_spec, lay_spec, beta_spec], beta_spec
 
-def _tile_onehot(slot_ref, tile, bt):
-    """(bn, bt) one-hot of this block's slots against table tile ``tile``
-    (slots outside the tile produce all-zero rows)."""
-    slot = slot_ref[...][0]                                  # (bn,) int32
-    col = jax.lax.broadcasted_iota(jnp.int32, (slot.shape[0], bt), 1)
-    return (slot[:, None] - tile * bt == col).astype(jnp.float32)
+    out = _grouped_call(
+        _fused_body, (v_block, v_tile, v_phase),
+        (_with_row_axis(slot_lay), _with_row_axis(coeff_lay), beta3), specs,
+        jax.ShapeDtypeStruct(beta3.shape, jnp.float32), interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((block_t, k), jnp.float32)])
+    return out[:, 0] if beta_lay.ndim == 2 else out
 
 
 def _scatter_blocked_body(vs_block_ref, vs_tile_ref, slot_ref, contrib_ref,
-                          table_ref, *, multi: bool):
+                          table_ref):
     """One scatter visit of the blocked split schedule: layout block
     ``vs_block[i, j]`` accumulates into HBM table tile ``vs_tile[i, j]``.
 
@@ -246,8 +295,8 @@ def _scatter_blocked_body(vs_block_ref, vs_tile_ref, slot_ref, contrib_ref,
     output tile stays resident between them and is zeroed exactly once, on
     its first visit — including tiles no point hashes into, which get one
     visit against the all-padding layout block (coeff 0 ⇒ adds nothing).
-    ``multi`` selects the multi-RHS blocks: the k columns share each
-    one-hot — (k, bn)×(bn, bt) per visit against a (1, k, bt) table block.
+    The k columns of a multi-RHS block share each mask against a (k, bt)
+    table block.
     """
     i, j = pl.program_id(0), pl.program_id(1)
     tile = vs_tile_ref[i, j]
@@ -257,31 +306,23 @@ def _scatter_blocked_body(vs_block_ref, vs_tile_ref, slot_ref, contrib_ref,
     def _zero():
         table_ref[...] = jnp.zeros_like(table_ref)
 
-    onehot = _tile_onehot(slot_ref, tile, table_ref.shape[-1])
-    contrib = contrib_ref[...][0] if multi else contrib_ref[...]
-    upd = jax.lax.dot_general(contrib, onehot, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    table_ref[...] += upd[None] if multi else upd
+    hit = _hit(slot_ref, tile, table_ref.shape[-1])
+    table_ref[...] += _cols_to_rows(_scatter(hit, contrib_ref[...]))
 
 
-def _gather_blocked_body(vg_tile_ref, slot_ref, table_ref, out_ref, *,
-                         multi: bool):
+def _gather_blocked_body(vg_tile_ref, slot_ref, table_ref, out_ref):
     """One gather visit: layout block j reads the ONE tile it addresses.
     Every block is written exactly once, so no accumulation or init pass."""
     i, j = pl.program_id(0), pl.program_id(1)
-    tile = vg_tile_ref[i, j]
-    onehot = _tile_onehot(slot_ref, tile, table_ref.shape[-1])
-    table = table_ref[...][0] if multi else table_ref[...]
-    out = jax.lax.dot_general(table, onehot, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    out_ref[...] = out[None] if multi else out
+    hit = _hit(slot_ref, vg_tile_ref[i, j], table_ref.shape[-1])
+    out_ref[...] = _gather(hit, _rows_to_cols(table_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("num_tiles", "block_n",
                                              "block_t", "interpret"))
 def bin_scatter_blocked_pallas(vs_block, vs_tile, slot_lay, contrib_lay, *,
                                num_tiles: int, block_n: int, block_t: int,
-                               interpret: bool = True):
+                               interpret: bool):
     """Visit-list scatter over the slot-blocked layout — the split contract
     (the (m, B) table lands in HBM, psum-able) at the fused kernel's
     O(n/bn + B/bt) grid cost.
@@ -296,40 +337,31 @@ def bin_scatter_blocked_pallas(vs_block, vs_tile, slot_lay, contrib_lay, *,
     of contrib_lay[s, ..., p].
     """
     m = slot_lay.shape[0]
-    n_vis = vs_block.shape[1]
-    lay_spec = pl.BlockSpec((1, block_n), lambda i, j, vb, vt: (i, vb[i, j]))
-    multi = contrib_lay.ndim == 3
-    body = functools.partial(_scatter_blocked_body, multi=multi)
-    if not multi:
-        contrib_spec = lay_spec
-        out_spec = pl.BlockSpec((1, block_t),
-                                lambda i, j, vb, vt: (i, vt[i, j]))
-        out_shape = (m, num_tiles * block_t)
-    else:
-        k = contrib_lay.shape[1]
-        contrib_spec = pl.BlockSpec((1, k, block_n),
-                                    lambda i, j, vb, vt: (i, 0, vb[i, j]))
-        out_spec = pl.BlockSpec((1, k, block_t),
-                                lambda i, j, vb, vt: (i, 0, vt[i, j]))
-        out_shape = (m, k, num_tiles * block_t)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(m, n_vis),
-        in_specs=[lay_spec, contrib_spec],
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        interpret=interpret,
-    )(vs_block, vs_tile, slot_lay, contrib_lay)
+    contrib3 = _with_row_axis(contrib_lay.astype(jnp.float32))
+    k = contrib3.shape[1]
+
+    def specs(s):
+        def block(i, j, vb, vt):
+            return i + s, 0, vb[i, j]
+
+        def tile(i, j, vb, vt):
+            return i + s, 0, vt[i, j]
+        return ([pl.BlockSpec((None, 1, block_n), block),
+                 pl.BlockSpec((None, k, block_n), block)],
+                pl.BlockSpec((None, k, block_t), tile))
+
+    out = _grouped_call(
+        _scatter_blocked_body, (vs_block, vs_tile),
+        (_with_row_axis(slot_lay), contrib3), specs,
+        jax.ShapeDtypeStruct((m, k, num_tiles * block_t), jnp.float32),
+        interpret=interpret)
+    return out[:, 0] if contrib_lay.ndim == 2 else out
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t",
                                              "interpret"))
 def bin_gather_blocked_pallas(vg_tile, slot_lay, tables, *, block_n: int,
-                              block_t: int, interpret: bool = True):
+                              block_t: int, interpret: bool):
     """Visit-list gather over the slot-blocked layout: layout block j reads
     only the ONE table tile ``vg_tile[i, j]`` it addresses — NB grid steps
     per instance instead of the (L/bn)·(B/bt) cross product.
@@ -342,37 +374,29 @@ def bin_gather_blocked_pallas(vg_tile, slot_lay, tables, *, block_n: int,
     n_vis = vg_tile.shape[1]
     if layout_len != n_vis * block_n:
         raise ValueError("layout length must equal visits * block_n")
-    lay_spec = pl.BlockSpec((1, block_n), lambda i, j, vt: (i, j))
-    multi = tables.ndim == 3
-    body = functools.partial(_gather_blocked_body, multi=multi)
-    if not multi:
-        table_spec = pl.BlockSpec((1, block_t),
-                                  lambda i, j, vt: (i, vt[i, j]))
-        out_spec = lay_spec
-        out_shape = (m, layout_len)
-    else:
-        k = tables.shape[1]
-        table_spec = pl.BlockSpec((1, k, block_t),
-                                  lambda i, j, vt: (i, 0, vt[i, j]))
-        out_spec = pl.BlockSpec((1, k, block_n),
-                                lambda i, j, vt: (i, 0, j))
-        out_shape = (m, k, layout_len)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m, n_vis),
-        in_specs=[lay_spec, table_spec],
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        interpret=interpret,
-    )(vg_tile, slot_lay, tables)
+    tables3 = _with_row_axis(tables.astype(jnp.float32))
+    k = tables3.shape[1]
+
+    def specs(s):
+        def block(i, j, vt):
+            return i + s, 0, j
+
+        def tile(i, j, vt):
+            return i + s, 0, vt[i, j]
+        return ([pl.BlockSpec((None, 1, block_n), block),
+                 pl.BlockSpec((None, k, block_t), tile)],
+                pl.BlockSpec((None, k, block_n), block))
+
+    out = _grouped_call(
+        _gather_blocked_body, (vg_tile,), (_with_row_axis(slot_lay), tables3),
+        specs,
+        jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
+        interpret=interpret)
+    return out[:, 0] if tables.ndim == 2 else out
 
 
 def _route_pack_body(inst_ref, blk_ref, tile_ref, flag_ref, cell_ref,
-                     contrib_ref, out_ref, *, multi: bool):
+                     contrib_ref, prev_ref, out_ref):
     """One visit of the hash-join route-pack schedule (flat grid).
 
     The output is the flat all_to_all send buffer — ONE buffer shared by
@@ -380,11 +404,14 @@ def _route_pack_body(inst_ref, blk_ref, tile_ref, flag_ref, cell_ref,
     rather than per instance: visits to a tile are contiguous in grid order,
     each tile's segment opens with a mandatory zero visit (flag 1), real
     visits (flag 0) accumulate one layout block's per-point contributions
-    into the tile via the one-hot MXU product (duplicate (instance, slot)
-    points hit the same cell row — the bucket segment-sum happens inside the
-    dot), and trailing no-ops (flag 2) re-target the last tile so the final
+    into the tile through the mask (duplicate (instance, slot) points hit
+    the same cell — the bucket segment-sum happens inside the reduction),
+    and trailing no-ops (flag 2) re-target the last tile so the final
     writebacks are idempotent.  Dropped / padding layout positions carry the
-    out-of-range sentinel cell and produce all-zero one-hot rows.
+    out-of-range sentinel cell and leave empty mask columns.  A call
+    runs one chunk of the schedule: when its first visit continues a tile
+    segment, the tile resumes from ``prev_ref``, the buffer the previous
+    chunk wrote (aliased to this call's output).
     """
     j = pl.program_id(0)
     flag = flag_ref[j]
@@ -393,17 +420,18 @@ def _route_pack_body(inst_ref, blk_ref, tile_ref, flag_ref, cell_ref,
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
+    @pl.when((j == 0) & (flag != 1))
+    def _resume():
+        out_ref[...] = prev_ref[...]
+
     @pl.when(flag == 0)
     def _add():
-        onehot = _tile_onehot(cell_ref, tile_ref[j], out_ref.shape[-1])
-        contrib = contrib_ref[...][0] if multi else contrib_ref[...]
-        out_ref[...] += jax.lax.dot_general(
-            contrib, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        hit = _hit(cell_ref, tile_ref[j], out_ref.shape[-1])
+        out_ref[...] += _cols_to_rows(_scatter(hit, contrib_ref[...]))
 
 
 def _route_unpack_body(blk_ref, tile_ref, flag_ref, cell_ref, coeff_ref,
-                       back_ref, out_ref, *, multi: bool):
+                       back_ref, out_ref):
     """One visit of the hash-join route-unpack schedule (per-instance grid).
 
     Reads the received wire values back through each layout block's cell
@@ -424,19 +452,16 @@ def _route_unpack_body(blk_ref, tile_ref, flag_ref, cell_ref, coeff_ref,
 
     @pl.when(flag == 0)
     def _acc():
-        onehot = _tile_onehot(cell_ref, tile_ref[i, j], back_ref.shape[-1])
-        vals = jax.lax.dot_general(                  # (1|k, bn)
-            back_ref[...], onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        upd = coeff_ref[...] * vals
-        out_ref[...] += upd[None] if multi else upd
+        hit = _hit(cell_ref, tile_ref[i, j], back_ref.shape[-1])
+        out_ref[...] += coeff_ref[...] * _gather(
+            hit, _rows_to_cols(back_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("num_cell_tiles", "block_n",
                                              "block_t", "interpret"))
 def route_pack_pallas(p_inst, p_block, p_tile, p_flag, cell_lay, contrib_lay,
                       *, num_cell_tiles: int, block_n: int, block_t: int,
-                      interpret: bool = True):
+                      interpret: bool):
     """Hash-join route pack: per-point contributions -> flat send cells.
 
     p_inst/p_block/p_tile/p_flag (V,) int32 — the flat tile-segmented
@@ -447,38 +472,37 @@ def route_pack_pallas(p_inst, p_block, p_tile, p_flag, cell_lay, contrib_lay,
     buffer (1, num_cell_tiles·block_t) — or (k, ·) — with
     buffer[..., c] = sum over layout positions p with cell_lay[p] == c.
     """
-    multi = contrib_lay.ndim == 3
-    lay_spec = pl.BlockSpec((1, block_n),
-                            lambda j, pi, pb, pt, pf: (pi[j], pb[j]))
-    if multi:
-        k = contrib_lay.shape[1]
-        contrib_spec = pl.BlockSpec(
-            (1, k, block_n), lambda j, pi, pb, pt, pf: (pi[j], 0, pb[j]))
-        out_rows = k
-    else:
-        contrib_spec = lay_spec
-        out_rows = 1
-    out_spec = pl.BlockSpec((out_rows, block_t),
-                            lambda j, pi, pb, pt, pf: (0, pt[j]))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(p_inst.shape[0],),
-        in_specs=[lay_spec, contrib_spec],
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
-        functools.partial(_route_pack_body, multi=multi),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, num_cell_tiles * block_t),
-                                       jnp.float32),
-        interpret=interpret,
-    )(p_inst, p_block, p_tile, p_flag, cell_lay, contrib_lay)
+    contrib3 = _with_row_axis(contrib_lay.astype(jnp.float32))
+    k = contrib3.shape[1]
+
+    def block(j, pi, pb, pt, pf):
+        return pi[j], 0, pb[j]
+
+    tile_spec = pl.BlockSpec((k, block_t), lambda j, pi, pb, pt, pf: (0, pt[j]))
+    out_shape = jax.ShapeDtypeStruct((k, num_cell_tiles * block_t),
+                                     jnp.float32)
+    chunk = SMEM_SCHEDULE_BYTES // (4 * 4)
+    out = jnp.zeros(out_shape.shape, jnp.float32)
+    for c0 in range(0, p_inst.shape[0], chunk):
+        sched = [a[c0:c0 + chunk] for a in (p_inst, p_block, p_tile, p_flag)]
+        out = pl.pallas_call(
+            _route_pack_body,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(sched[0].shape[0],),
+                in_specs=[pl.BlockSpec((None, 1, block_n), block),
+                          pl.BlockSpec((None, k, block_n), block), tile_spec],
+                out_specs=tile_spec),
+            out_shape=out_shape,
+            input_output_aliases={6: 0},
+            interpret=interpret,
+        )(*sched, _with_row_axis(cell_lay), contrib3, out)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t",
                                              "interpret"))
 def route_unpack_pallas(u_block, u_tile, u_flag, cell_lay, coeff_lay, back, *,
-                        block_n: int, block_t: int, interpret: bool = True):
+                        block_n: int, block_t: int, interpret: bool):
     """Hash-join route unpack: received wire values -> coeff-weighted layout.
 
     u_block/u_tile/u_flag (m, VB) int32 — the per-instance visit schedule;
@@ -488,37 +512,29 @@ def route_unpack_pallas(u_block, u_tile, u_flag, cell_lay, coeff_lay, back, *,
     out_lay[s, ..., p] = coeff_lay[s, p] · back[..., cell_lay[s, p]]
     (sentinel cells gather 0).
     """
-    m = cell_lay.shape[0]
-    n_vis = u_block.shape[1]
-    multi = back.shape[0] > 1
-    lay_spec = pl.BlockSpec((1, block_n),
-                            lambda i, j, ub, ut, uf: (i, ub[i, j]))
-    back_spec = pl.BlockSpec((back.shape[0], block_t),
-                             lambda i, j, ub, ut, uf: (0, ut[i, j]))
-    if multi:
-        k = back.shape[0]
-        out_spec = pl.BlockSpec((1, k, block_n),
-                                lambda i, j, ub, ut, uf: (i, 0, ub[i, j]))
-        out_shape = (m, k, cell_lay.shape[1])
-    else:
-        out_spec = lay_spec
-        out_shape = (m, cell_lay.shape[1])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(m, n_vis),
-        in_specs=[lay_spec, lay_spec, back_spec],
-        out_specs=out_spec,
-    )
-    return pl.pallas_call(
-        functools.partial(_route_unpack_body, multi=multi),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        interpret=interpret,
-    )(u_block, u_tile, u_flag, cell_lay, coeff_lay, back)
+    m, layout_len = cell_lay.shape
+    k = back.shape[0]
+
+    def specs(s):
+        def block(i, j, ub, ut, uf):
+            return i + s, 0, ub[i, j]
+        return ([pl.BlockSpec((None, 1, block_n), block),
+                 pl.BlockSpec((None, 1, block_n), block),
+                 pl.BlockSpec((k, block_t),
+                              lambda i, j, ub, ut, uf: (0, ut[i, j]))],
+                pl.BlockSpec((None, k, block_n), block))
+
+    out = _grouped_call(
+        _route_unpack_body, (u_block, u_tile, u_flag),
+        (_with_row_axis(cell_lay), _with_row_axis(coeff_lay),
+         back.astype(jnp.float32)), specs,
+        jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
+        interpret=interpret)
+    return out[:, 0] if k == 1 else out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n", "block_t"))
-def bin_gather_pallas(slot, tables, *, interpret: bool = True,
+def bin_gather_pallas(slot, tables, *, interpret: bool,
                       block_n: int = BLOCK_N, block_t: int = BLOCK_T):
     """slot (m, n) int32; tables (m, B) f32.  Returns out (m, n) f32 with
     out[s, i] = tables[s, slot[s, i]]."""
@@ -527,13 +543,13 @@ def bin_gather_pallas(slot, tables, *, interpret: bool = True,
     bn, bt = min(block_n, n), min(block_t, table_size)
     if n % bn or table_size % bt:
         raise ValueError("n and table_size must divide their block sizes")
-    grid = (m, n // bn, table_size // bt)
+    point_spec = pl.BlockSpec((None, 1, bn), lambda i, j, t: (i, 0, j))
     return pl.pallas_call(
         _gather_body,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bn), lambda i, j, t: (i, j)),
-                  pl.BlockSpec((1, bt), lambda i, j, t: (i, t))],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, t: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        grid=(m, n // bn, table_size // bt),
+        in_specs=[point_spec,
+                  pl.BlockSpec((None, 1, bt), lambda i, j, t: (i, 0, t))],
+        out_specs=point_spec,
+        out_shape=jax.ShapeDtypeStruct((m, 1, n), jnp.float32),
         interpret=interpret,
-    )(slot, tables)
+    )(_with_row_axis(slot), _with_row_axis(tables.astype(jnp.float32)))[:, 0]

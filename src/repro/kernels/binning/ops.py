@@ -19,20 +19,18 @@ Shapes are padded internally: ``n`` (points) is padded to the block size with
 an always-zero contribution in slot 0, and ``table_size`` is padded up to a
 multiple of the table tile (padded slots are never addressed, so results are
 exact).  Callers never see padding — outputs are trimmed to logical shapes.
-``interpret=None`` auto-selects Pallas interpret mode from the platform
-(compiled on TPU, interpreted elsewhere).
+``interpret`` is required: the caller (``core.operator``) decides it from
+the platform the program is placed on — the Pallas interpreter on CPU,
+compiled kernels on TPU.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ...backend import default_interpret
-from ...core.wlsh import (TableIndex, table_loads, table_matvec_fused,
-                          table_readout)
+from ...core.wlsh import TableIndex
 from .kernel import (BLOCK_N, BLOCK_T, bin_fused_matvec_pallas,
                      bin_gather_blocked_pallas, bin_gather_pallas,
                      bin_scatter_blocked_pallas, bin_scatter_pallas)
-from .ref import bin_gather_ref, bin_scatter_ref
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -66,8 +64,7 @@ def _beta_to_layout(lay, beta):
     return jnp.swapaxes(beta_lay, 1, 2) if beta.ndim == 2 else beta_lay
 
 
-def bin_loads_blocked_op(index: TableIndex, beta, *,
-                         interpret: bool | None = None):
+def bin_loads_blocked_op(index: TableIndex, beta, *, interpret: bool):
     """Visit-list split scatter: same (m, B[, k]) psum-able tables as
     ``bin_loads_op`` at the blocked layout's O(n/bn + B/bt) grid cost.
     Multi-RHS is native — the k columns share every one-hot tile product
@@ -78,8 +75,6 @@ def bin_loads_blocked_op(index: TableIndex, beta, *,
                          "with the pallas group; build it with "
                          "build_blocked_layout(parts='pallas'|'both') / a "
                          "pallas-backend build_index(blocked=True)")
-    if interpret is None:
-        interpret = default_interpret()
     beta_lay = _beta_to_layout(lay, beta)                    # (m,[ k,] L)
     coeff = lay.coeff_lay if beta.ndim == 1 else lay.coeff_lay[:, None, :]
     tables = bin_scatter_blocked_pallas(
@@ -89,8 +84,8 @@ def bin_loads_blocked_op(index: TableIndex, beta, *,
     return jnp.swapaxes(tables, 1, 2) if beta.ndim == 2 else tables
 
 
-def bin_readout_blocked_op(index: TableIndex, tables, *, average: bool = True,
-                           interpret: bool | None = None):
+def bin_readout_blocked_op(index: TableIndex, tables, *, interpret: bool,
+                           average: bool = True):
     """Visit-list split gather of (possibly psum-merged) tables: each layout
     block reads only the ONE tile it addresses; results map back to point
     order through the layout's ``inv_pos``."""
@@ -100,8 +95,6 @@ def bin_readout_blocked_op(index: TableIndex, tables, *, average: bool = True,
                          "with the pallas group; build it with "
                          "build_blocked_layout(parts='pallas'|'both') / a "
                          "pallas-backend build_index(blocked=True)")
-    if interpret is None:
-        interpret = default_interpret()
     multi = tables.ndim == 3
     bp = lay.num_tiles * lay.block_t
     t = jnp.swapaxes(tables, 1, 2) if multi else tables      # (m,[ k,] B)
@@ -119,9 +112,8 @@ def bin_readout_blocked_op(index: TableIndex, tables, *, average: bool = True,
     return jnp.mean(signed, axis=0) if average else jnp.sum(signed, axis=0)
 
 
-def bin_loads_op(index: TableIndex, beta, *, use_kernel: bool = True,
-                 interpret: bool | None = None, block_n: int = BLOCK_N,
-                 block_t: int = BLOCK_T):
+def bin_loads_op(index: TableIndex, beta, *, interpret: bool,
+                 block_n: int = BLOCK_N, block_t: int = BLOCK_T):
     """Kernel-backed ``table_loads``: (m, B) bucket-load tables for beta (n,),
     or (m, B, k) for a (n, k) RHS block.  An index carrying the slot-blocked
     layout takes the visit-list kernels (``bin_loads_blocked_op`` — multi-RHS
@@ -129,19 +121,14 @@ def bin_loads_op(index: TableIndex, beta, *, use_kernel: bool = True,
     shape the cross-product fallback (geometry A/B runs rebuild the layout
     via ``build_blocked_layout``); otherwise the cross-product scatter runs
     per column — either way the split path stays psum-able."""
-    if use_kernel and _split_layout(index) is not None:
+    if _split_layout(index) is not None:
         return bin_loads_blocked_op(index, beta, interpret=interpret)
     if beta.ndim == 2:
-        cols = [bin_loads_op(index, beta[:, j], use_kernel=use_kernel,
-                             interpret=interpret, block_n=block_n,
-                             block_t=block_t)
+        cols = [bin_loads_op(index, beta[:, j], interpret=interpret,
+                             block_n=block_n, block_t=block_t)
                 for j in range(beta.shape[1])]
         return jnp.stack(cols, axis=-1)
     contrib = (beta[None, :] * index.coeff).astype(jnp.float32)
-    if not use_kernel:
-        return bin_scatter_ref(index.slot, contrib, table_size=index.table_size)
-    if interpret is None:
-        interpret = default_interpret()
     bn, bt = _block_sizes(index.slot.shape[1], index.table_size, block_n,
                           block_t)
     # pad points into slot 0 with zero contribution (cannot perturb loads)
@@ -153,9 +140,9 @@ def bin_loads_op(index: TableIndex, beta, *, use_kernel: bool = True,
     return tables[:, :index.table_size]
 
 
-def bin_readout_op(index: TableIndex, tables, *, average: bool = True,
-                   use_kernel: bool = True, interpret: bool | None = None,
-                   block_n: int = BLOCK_N, block_t: int = BLOCK_T):
+def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
+                   average: bool = True, block_n: int = BLOCK_N,
+                   block_t: int = BLOCK_T):
     """Kernel-backed ``table_readout``: per-point loads combined over the m
     instances (mean when ``average``, else sum — the distributed path sums
     locally and divides by the global m after its psum).  ``tables`` is
@@ -164,44 +151,35 @@ def bin_readout_op(index: TableIndex, tables, *, average: bool = True,
     (``bin_readout_blocked_op``) at the layout's own geometry
     (``block_n``/``block_t`` here shape only the cross-product fallback);
     otherwise the cross-product kernel runs per column."""
-    if use_kernel and _split_layout(index) is not None:
+    if _split_layout(index) is not None:
         return bin_readout_blocked_op(index, tables, average=average,
                                       interpret=interpret)
     if tables.ndim == 3:
         cols = [bin_readout_op(index, tables[..., j], average=average,
-                               use_kernel=use_kernel, interpret=interpret,
-                               block_n=block_n, block_t=block_t)
+                               interpret=interpret, block_n=block_n,
+                               block_t=block_t)
                 for j in range(tables.shape[-1])]
         return jnp.stack(cols, axis=-1)
-    if not use_kernel:
-        vals = bin_gather_ref(index.slot, tables)
-    else:
-        if interpret is None:
-            interpret = default_interpret()
-        n = index.slot.shape[1]
-        bn, bt = _block_sizes(n, index.table_size, block_n, block_t)
-        slot_p, _ = _pad_points(index.slot, bn, value=0)
-        bp = _round_up(index.table_size, bt)
-        tables_p = jnp.pad(tables.astype(jnp.float32),
-                           ((0, 0), (0, bp - index.table_size)))
-        vals = bin_gather_pallas(slot_p, tables_p, interpret=interpret,
-                                 block_n=bn, block_t=bt)[:, :n]
+    n = index.slot.shape[1]
+    bn, bt = _block_sizes(n, index.table_size, block_n, block_t)
+    slot_p, _ = _pad_points(index.slot, bn, value=0)
+    bp = _round_up(index.table_size, bt)
+    tables_p = jnp.pad(tables.astype(jnp.float32),
+                       ((0, 0), (0, bp - index.table_size)))
+    vals = bin_gather_pallas(slot_p, tables_p, interpret=interpret,
+                             block_n=bn, block_t=bt)[:, :n]
     signed = vals * index.coeff
     return jnp.mean(signed, axis=0) if average else jnp.sum(signed, axis=0)
 
 
-def table_matvec_op(index: TableIndex, beta, *, use_kernel: bool = True,
-                    interpret: bool | None = None):
+def table_matvec_op(index: TableIndex, beta, *, interpret: bool):
     """Scatter then gather: the kernel-backed split WLSH table matvec."""
-    tables = bin_loads_op(index, beta, use_kernel=use_kernel,
-                          interpret=interpret)
-    return bin_readout_op(index, tables, use_kernel=use_kernel,
-                          interpret=interpret)
+    tables = bin_loads_op(index, beta, interpret=interpret)
+    return bin_readout_op(index, tables, interpret=interpret)
 
 
-def bin_fused_matvec_op(index: TableIndex, beta, *, average: bool = True,
-                        use_kernel: bool = True,
-                        interpret: bool | None = None):
+def bin_fused_matvec_op(index: TableIndex, beta, *, interpret: bool,
+                        average: bool = True):
     """Fused one-pass WLSH table matvec off the slot-blocked layout.
 
     Requires ``index.blocked`` (see ``core.wlsh.build_blocked_layout``).  The
@@ -219,14 +197,6 @@ def bin_fused_matvec_op(index: TableIndex, beta, *, average: bool = True,
                          "pallas group; build it with build_blocked_layout"
                          "(parts='pallas'|'both') / a pallas-backend "
                          "build_index(blocked=True)")
-    if not use_kernel:
-        # pallas-built indexes don't carry the reference segment group;
-        # degrade to the split composition rather than refuse
-        if lay.perm is not None:
-            return table_matvec_fused(index, beta, average=average)
-        return table_readout(index, table_loads(index, beta), average=average)
-    if interpret is None:
-        interpret = default_interpret()
     m = index.slot.shape[0]
     multi = beta.ndim == 2
     pad = jnp.zeros((1,) + beta.shape[1:], jnp.float32)

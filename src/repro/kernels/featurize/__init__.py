@@ -1,3 +1,2 @@
 from .ops import featurize_op
 from .kernel import featurize_pallas
-from .ref import featurize_ref

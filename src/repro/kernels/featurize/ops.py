@@ -1,26 +1,18 @@
-"""Public op: WLSH featurization with automatic padding + kernel/ref dispatch."""
+"""Public op: WLSH featurization with automatic padding around the kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ...backend import default_interpret
 from ...core.bucket_fns import BucketFn
 from ...core.lsh import Features, LSHParams
 from .kernel import BLOCK_N, featurize_pallas
-from .ref import featurize_ref
 
 
-def featurize_op(params: LSHParams, f: BucketFn, x, *, use_kernel: bool = True,
-                 interpret: bool | None = None) -> Features:
+def featurize_op(params: LSHParams, f: BucketFn, x, *,
+                 interpret: bool) -> Features:
     """Drop-in replacement for repro.core.lsh.featurize backed by the Pallas
     kernel.  Points are padded to the kernel block size and trimmed after;
-    ``interpret=None`` auto-selects from the platform (compiled on TPU)."""
-    if not use_kernel:
-        k1, k2, wt, sg = featurize_ref(x, params.w, params.z, params.r1,
-                                       params.r2, f=f)
-        return Features(key1=k1, key2=k2, weight=wt, sign=sg)
-    if interpret is None:
-        interpret = default_interpret()
+    ``interpret`` selects the Pallas interpreter (CPU only)."""
     n = x.shape[0]
     bn = min(BLOCK_N, max(128, -(-n // 128) * 128))
     np_ = -(-n // bn) * bn

@@ -35,6 +35,7 @@ import time
 import numpy as np
 
 from .. import obs
+from ..compile_cache import use_compile_cache
 from ..serve import (DeadlineExceeded, LifecycleConfig, MicroBatcher,
                      Overloaded, Predictor, ServingRuntime, ShardedPredictor,
                      WorkerCrashed, bucket_sizes, parse_mesh_shape,
@@ -178,7 +179,7 @@ def _fit(*, n: int = 1024, d: int = 8, m: int = 128, seed: int = 0):
     y = jax.random.normal(jax.random.fold_in(key, 1), (n,))
     spec = WLSHKernelSpec(bucket=get_bucket_fn("rect"))
     model = wlsh_krr_fit(jax.random.fold_in(key, 2), x, y, spec, m=m,
-                         lam=0.5, backend="reference")
+                         lam=0.5, backend="auto")
     return model, np.asarray(x, np.float32)
 
 
@@ -564,6 +565,7 @@ def main(argv=None) -> int:
                          "(headless runs: scrape-free flight recorder)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     mesh_shape = parse_mesh_shape(args.mesh) if args.mesh else None
     server = None

@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.krr_train --dataset forest \
         --scale 0.01 --m 64 --lam 0.5
 
-On this CPU container the mesh is whatever devices exist (1 by default; use
-XLA_FLAGS=--xla_force_host_platform_device_count=8 to exercise the collective
-paths).  On a real fleet the same code runs on the production mesh — the step
-function is the one the multi-pod dry-run lowers (launch/dryrun.py --cells krr).
+The mesh is whatever devices exist: the chips of a TPU host, or on the CPU
+one device (XLA_FLAGS=--xla_force_host_platform_device_count=8 exercises the
+collective paths).  On a real fleet the same code runs on the production
+mesh — the step function is the one the multi-pod dry-run lowers
+(launch/dryrun.py --cells krr).
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs
+from ..compile_cache import use_compile_cache
 from ..core.bucket_fns import get_bucket_fn
 from ..core.distributed import (KRRStepConfig, OVERFLOW_POLICIES,
                                 make_krr_predict, make_krr_predict_hashjoin,
@@ -25,6 +27,7 @@ from ..core.distributed import (KRRStepConfig, OVERFLOW_POLICIES,
 from ..core.precond import DEFAULT_NYSTROM_RANK
 from ..core.lsh import GammaPDF
 from ..data import make_regression_dataset
+from ..backend import platform_of, resolve_backend
 from .mesh import make_host_mesh
 
 # hashjoin all_to_all payload dtypes (configs.wlsh_krr.wire_dtype mirrors)
@@ -113,6 +116,7 @@ def main() -> int:
                     help="append a JSONL metrics snapshot to PATH on exit")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     xtr, ytr, xte, yte = make_regression_dataset(args.dataset, args.seed,
                                                  scale=args.scale)
@@ -143,8 +147,7 @@ def main() -> int:
         ytr = jnp.concatenate([ytr[:, None], probes], axis=1)
 
     if args.trace_dir:
-        if not obs.start_trace(args.trace_dir):
-            print("[krr] --trace-dir ignored: jax.profiler unavailable")
+        obs.start_trace(args.trace_dir)
     if args.table_mode == "hashjoin":
         # the resilient runner applies --overflow to the step's fault
         # counters and retries a non-finite solve once on an f32 wire
@@ -177,10 +180,14 @@ def main() -> int:
         yhat, resnorm = yhat[:, 0], resnorm[0]
     rmse = float(jnp.sqrt(jnp.mean((yhat - yte) ** 2)))
     print(f"[krr] {args.dataset} scale={args.scale}: n={n_tr} d={d} "
-          f"m={args.m} B={table} backend={args.backend} fused={args.fused} "
+          f"m={args.m} B={table} backend={args.backend} "
+          f"({resolve_backend(cfg.backend, platform_of(mesh))}) "
+          f"fused={args.fused} "
           f"precond={args.precond} num_rhs={args.num_rhs} "
           f"table_mode={args.table_mode} wire={args.wire_dtype}")
-    print(f"[krr] fit {t_fit:.2f}s on {n_shards} shard(s); "
+    dev = mesh.devices.flat[0]
+    print(f"[krr] fit {t_fit:.2f}s on {n_shards} shard(s) "
+          f"({dev.platform} {dev.device_kind}); "
           f"CG residual {float(resnorm):.2e}; test RMSE {rmse:.4f} "
           f"(label std = 1.0)")
     _print_solve_metrics(args)

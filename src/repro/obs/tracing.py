@@ -314,33 +314,27 @@ def annotation(name: str):
 _trace_dir: str | None = None
 
 
-def start_trace(trace_dir: str) -> bool:
+def start_trace(trace_dir: str) -> None:
     """Begin a ``jax.profiler`` trace into ``trace_dir`` (view with
     ``tensorboard --logdir``) and turn on per-span TraceAnnotations so the
-    host spans appear on the trace timeline.  Returns False (and records
-    nothing) if the profiler is unavailable."""
+    host spans appear on the trace timeline.  A profiler that cannot start
+    raises: a run asked to trace must not pass without its trace."""
     global _trace_dir
-    try:
-        import jax.profiler
-        jax.profiler.start_trace(trace_dir)
-    except Exception:
-        return False
+    import jax.profiler
+    jax.profiler.start_trace(trace_dir)
     _trace_dir = trace_dir
     set_jax_annotations(True)
     _registry.counter(
         "trace_sessions_total", "profiler trace captures started").inc()
-    return True
 
 
 def stop_trace() -> str | None:
-    """End the active profiler trace; returns its directory (or None)."""
+    """End the active profiler trace and write it out; returns its directory
+    (or None when no trace is active).  A failed write raises."""
     global _trace_dir
     d, _trace_dir = _trace_dir, None
     set_jax_annotations(False)
     if d is not None:
-        try:
-            import jax.profiler
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        import jax.profiler
+        jax.profiler.stop_trace()
     return d

@@ -9,6 +9,10 @@ pass ``--runslow`` to include them.
 import jax
 import pytest
 
+# tests never read or write JAX's persistent compile cache, whatever the
+# environment sets: only the entry points place it (repro.compile_cache)
+jax.config.update("jax_enable_compilation_cache", False)
+
 
 @pytest.fixture(scope="session")
 def rng():

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.backend import resolve_backend
+from repro.backend import platform_of, resolve_backend, resolve_interpret
 from repro.core import (GammaPDF, WLSHKernelSpec, get_bucket_fn, make_operator,
                         sample_lsh_params, wlsh_krr_fit, wlsh_krr_predict)
 from repro.core.operator import default_table_size
@@ -122,6 +122,41 @@ def test_auto_backend_resolution(monkeypatch):
     assert resolve_backend("reference") == "reference"  # ...but not explicit
     with pytest.raises(ValueError):
         resolve_backend("mps")
+
+
+def test_interpret_follows_placement():
+    """The Pallas interpreter is the CPU path only: chosen by default off
+    the TPU, never on it, and refused there when asked for."""
+    assert resolve_interpret(None, "cpu") is True
+    assert resolve_interpret(False, "cpu") is False
+    assert resolve_interpret(None, "tpu") is False
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True, "tpu")
+    cpu = jax.devices("cpu")[0]
+    mesh = jax.sharding.Mesh(np.array([cpu]), ("data",))
+    assert platform_of(mesh) == "cpu"
+    assert platform_of(jax.device_put(jnp.zeros(3), cpu)) == "cpu"
+    assert platform_of(np.zeros(3)) == jax.devices()[0].platform
+
+
+def test_compile_cache_directory(monkeypatch):
+    """Entry points keep the persistent cache where JAX_COMPILATION_CACHE_DIR
+    says and set nothing else; unset, in the repo's git-ignored .jax_cache."""
+    import pathlib
+    from repro.compile_cache import REPO_CACHE, use_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(REPO_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert REPO_CACHE == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 def test_default_table_size_heuristic():

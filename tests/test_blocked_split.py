@@ -17,7 +17,7 @@ import pytest
 from repro.core import (GammaPDF, get_bucket_fn, make_operator,
                         sample_lsh_params)
 from repro.core.distributed import _routing_maps
-from repro.core.wlsh import (BLOCKED_SPLIT_N, BLOCKED_SPLIT_T, TableIndex,
+from repro.core.wlsh import (BLOCKED_N, BLOCKED_T, TableIndex,
                              build_blocked_layout, build_table_index,
                              table_loads, table_matvec, table_readout)
 from repro.hlo_analysis import count_ops
@@ -97,10 +97,10 @@ def test_blocked_split_matvec_through_operator():
     op = make_operator(lsh, get_bucket_fn("rect"), table_size,
                        backend="pallas", fused=False)
     feats = op.featurize(x)
-    bidx = op.build_index(feats, blocked=True)       # split-tuned geometry
+    bidx = op.build_index(feats, blocked=True)       # the one kernel geometry
     assert bidx.blocked is not None
-    assert bidx.blocked.block_n == BLOCKED_SPLIT_N
-    assert bidx.blocked.block_t == BLOCKED_SPLIT_T
+    assert bidx.blocked.block_n == BLOCKED_N
+    assert bidx.blocked.block_t == BLOCKED_T
     ref = make_operator(lsh, get_bucket_fn("rect"), table_size,
                         backend="reference", fused=False)
     ridx = ref.build_index(feats, blocked=False)
@@ -225,8 +225,8 @@ def _route_setup(m=3, n=200, table_size=1024, n_shards=2, cap_factor=2.0,
     slot = jax.random.randint(key, (m, n), 0, table_size).astype(jnp.int32)
     coeff = jax.random.normal(jax.random.fold_in(key, 1), (m, n))
     lay = build_blocked_layout(slot, coeff, table_size,
-                               block_n=BLOCKED_SPLIT_N,
-                               block_t=BLOCKED_SPLIT_T, parts="both")
+                               block_n=BLOCKED_N,
+                               block_t=BLOCKED_T, parts="both")
     pt_cell, _, spp, cap, _, _ = _routing_maps(slot, lay, n_shards,
                                                table_size, cap_factor)
     nb = n_shards * cap
@@ -305,8 +305,8 @@ def test_route_schedule_contains_no_sort():
     slot = jax.random.randint(key, (m, n), 0, table_size).astype(jnp.int32)
     coeff = jax.random.normal(jax.random.fold_in(key, 1), (m, n))
     lay = build_blocked_layout(slot, coeff, table_size,
-                               block_n=BLOCKED_SPLIT_N,
-                               block_t=BLOCKED_SPLIT_T, parts="both")
+                               block_n=BLOCKED_N,
+                               block_t=BLOCKED_T, parts="both")
 
     def plan_fn(s):
         # lay closed over (its block geometry fields are static ints)
@@ -316,3 +316,70 @@ def test_route_schedule_contains_no_sort():
 
     hlo = jax.jit(plan_fn).lower(slot).compile().as_text()
     assert count_ops(hlo, "sort") == 0
+
+
+# ---------------------------------------------------------------------------
+# visit schedules larger than one call's SMEM budget
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kernel", ["fused", "fused_k4", "scatter", "gather",
+                                    "pack", "unpack"])
+def test_visit_kernels_split_over_smem_budget(monkeypatch, kernel):
+    """A schedule over the SMEM budget runs as several calls — instance
+    groups for the per-instance kernels, consecutive chunks (each resuming
+    the tile the previous one left open) for the flat route pack — and must
+    give exactly the one-call result."""
+    from repro.kernels.binning import (bin_fused_matvec_pallas,
+                                       bin_gather_blocked_pallas,
+                                       bin_scatter_blocked_pallas,
+                                       route_pack_pallas, route_unpack_pallas)
+    from repro.kernels.binning import kernel as kmod
+    lay, _, plan, nb, coeff = _route_setup(m=4)
+    sched = plan.sched
+    key = jax.random.PRNGKey(12)
+    m, length = lay.slot_lay.shape
+    beta = jax.random.normal(key, (m, length))
+    beta4 = jax.random.normal(key, (m, 4, length))
+    calls = {
+        "fused": (lambda: bin_fused_matvec_pallas(
+            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
+            lay.coeff_lay, beta, block_n=lay.block_n, block_t=lay.block_t,
+            interpret=True), 3 * lay.v_block.shape[1]),
+        "fused_k4": (lambda: bin_fused_matvec_pallas(
+            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
+            lay.coeff_lay, beta4, block_n=lay.block_n, block_t=lay.block_t,
+            interpret=True), 3 * lay.v_block.shape[1]),
+        "scatter": (lambda: bin_scatter_blocked_pallas(
+            lay.vs_block, lay.vs_tile, lay.slot_lay, beta,
+            num_tiles=lay.num_tiles, block_n=lay.block_n,
+            block_t=lay.block_t, interpret=True), 2 * lay.vs_block.shape[1]),
+        "gather": (lambda: bin_gather_blocked_pallas(
+            lay.vg_tile, lay.slot_lay,
+            jax.random.normal(key, (m, lay.num_tiles * lay.block_t)),
+            block_n=lay.block_n, block_t=lay.block_t, interpret=True),
+            lay.vg_tile.shape[1]),
+        "pack": (lambda: route_pack_pallas(
+            sched.p_inst, sched.p_block, sched.p_tile, sched.p_flag,
+            plan.cell_lay, beta, num_cell_tiles=sched.num_cell_tiles,
+            block_n=lay.block_n, block_t=sched.block_t, interpret=True),
+            4 * 7),
+        "unpack": (lambda: route_unpack_pallas(
+            sched.u_block, sched.u_tile, sched.u_flag, plan.cell_lay,
+            lay.coeff_lay, jax.random.normal(
+                key, (1, sched.num_cell_tiles * sched.block_t)),
+            block_n=lay.block_n, block_t=sched.block_t, interpret=True),
+            3 * sched.u_block.shape[1]),
+    }
+    call, words = calls[kernel]
+    whole = np.asarray(call())
+    jax.clear_caches()
+    # one instance per call, or 7-visit chunks of the flat pack schedule
+    monkeypatch.setattr(kmod, "SMEM_SCHEDULE_BYTES", 4 * words)
+    split = np.asarray(call())
+    jax.clear_caches()
+    np.testing.assert_array_equal(split, whole)
+    if kernel != "pack":
+        monkeypatch.setattr(kmod, "SMEM_SCHEDULE_BYTES", 4 * words - 4)
+        with pytest.raises(ValueError, match="SMEM"):
+            call()
+        jax.clear_caches()

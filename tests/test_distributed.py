@@ -77,7 +77,7 @@ def test_psum_matvec_2shards_matches_single_device(backend):
         in_specs=(P(("pod", "data"), None), P(("pod", "data")), lsh_specs),
         out_specs=P(("pod", "data")))
     def mv(x_local, beta_local, lsh_local):
-        op = _shard_operator(cfg, f, lsh_local, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
         i = op.build_index(op.featurize(x_local),
                            blocked=backend == "pallas")
         return make_distributed_matvec(cfg, op, n_data_shards=2)(
@@ -143,7 +143,7 @@ def test_psum_matvec_2shards_multi_rhs():
                   lsh_specs),
         out_specs=P(("pod", "data"), None))
     def mv(x_local, bk_local, lsh_local):
-        op = _shard_operator(cfg, f, lsh_local, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
         i = op.build_index(op.featurize(x_local), blocked=True)
         return make_distributed_matvec(cfg, op, n_data_shards=2)(
             i, bk_local)

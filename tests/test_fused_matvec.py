@@ -151,8 +151,10 @@ def test_fused_matvec_table_never_materialized_to_hbm():
             .lower(beta).compile().as_text()
         hlo_fused = jax.jit(lambda b: op_fused.matvec(idx, b)) \
             .lower(beta).compile().as_text()
-        assert materializes_shape(hlo_split, (m, table_size))
-        assert not materializes_shape(hlo_fused, (m, table_size))
+        # the kernels carry the table with a unit row axis, (m, 1, B)
+        shapes = ((m, table_size), (m, 1, table_size))
+        assert any(materializes_shape(hlo_split, s) for s in shapes)
+        assert not any(materializes_shape(hlo_fused, s) for s in shapes)
 
 
 def test_wlsh_krr_fit_bitwise_stable_across_fused_toggle():

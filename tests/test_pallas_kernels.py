@@ -28,7 +28,7 @@ def test_featurize_kernel_matches_ref(n, d, m, fname):
                                GammaPDF(2.0, 1.0))
     f = get_bucket_fn(fname)
     ref = featurize_jnp(params, f, x)
-    out = featurize_op(params, f, x, use_kernel=True, interpret=True)
+    out = featurize_op(params, f, x, interpret=True)
     assert bool(jnp.all(out.key1 == ref.key1))
     assert bool(jnp.all(out.key2 == ref.key2))
     np.testing.assert_allclose(out.weight, ref.weight, atol=2e-6)

@@ -254,12 +254,15 @@ def test_predictor_hosts_multiple_models(tmp_path):
     pred.load(str(tmp_path / "a2"))
     assert pred.artifact_ids == ["a1", "a2"]
     xq = np.asarray(x1[:32], np.float32)
-    np.testing.assert_array_equal(
-        pred.predict(xq, artifact_id="a1"),
-        np.asarray(wlsh_krr_predict(m1, xq)))
-    np.testing.assert_array_equal(
-        pred.predict(xq, artifact_id="a2"),
-        np.asarray(wlsh_krr_predict(m2, xq)))
+    # the Predictor's padding-bucket program and wlsh_krr_predict are
+    # programs of different shapes; XLA tiles the instance mean per shape,
+    # so they agree to 1 ulp of the batch's largest prediction, not bitwise
+    # (DESIGN.md §8)
+    for aid, model in (("a1", m1), ("a2", m2)):
+        want = np.asarray(wlsh_krr_predict(model, xq))
+        np.testing.assert_allclose(
+            pred.predict(xq, artifact_id=aid), want, rtol=0,
+            atol=np.spacing(np.abs(want).max()))
     with pytest.raises(KeyError):
         pred.predict(xq, artifact_id="missing")
 

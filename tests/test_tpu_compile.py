@@ -1,0 +1,171 @@
+"""Compile every Pallas kernel of kernels/featurize and kernels/binning for a
+TPU v5e, at the width of the paper's Forest Cover fit (d=54, n=500,000,
+m=64, B=2^21), with ``interpret=False``.
+
+Nothing runs: the TPU compiler is asked about a described v5e chip, so these
+tests catch what the Pallas interpreter cannot see — block shapes the chip's
+tiling refuses, unsupported ops, scalar memory over its size.  Each test
+also checks that the compiled program holds a Mosaic kernel
+(``tpu_custom_call``), so an interpret-mode lowering cannot pass for one.
+
+The topology is described inside a module fixture (never at import time):
+only the worker that runs this file loads the TPU library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.core import get_bucket_fn, make_operator, sample_lsh_params
+from repro.core.lsh import GammaPDF
+from repro.core.operator import default_table_size
+from repro.kernels.binning import (bin_fused_matvec_pallas,
+                                   bin_gather_blocked_pallas,
+                                   bin_gather_pallas,
+                                   bin_scatter_blocked_pallas,
+                                   bin_scatter_pallas, route_pack_pallas,
+                                   route_unpack_pallas)
+from repro.kernels.featurize import featurize_pallas
+
+M, N, D, B = 64, 500_000, 54, 1 << 21       # Forest Cover fit, m=64
+BN, BT = 128, 512                            # slot-blocked layout geometry
+TILES = B // BT
+NB = N // BN + TILES                         # layout blocks per instance
+N_LOC = N // 4                               # hash-join: 4 data shards
+NB_LOC = N_LOC // BN + TILES
+CELL_TILES = M * N_LOC // BT                 # wire cells / tile width
+VB = NB_LOC + CELL_TILES                     # unpack visits per instance
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                   # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but never read back without one: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def compile_tpu(one_chip, no_cache):
+    def run(fn, *shapes, **static):
+        args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                for s, dt in shapes]
+        text = jax.jit(functools.partial(fn, interpret=False, **static)) \
+            .lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return text
+    return run
+
+
+I32, U32, F32 = jnp.int32, jnp.uint32, jnp.float32
+
+
+@pytest.mark.parametrize("bucket", ["rect", "smooth"])
+def test_featurize_compiles(compile_tpu, bucket):
+    n_pad = -(-N // 1024) * 1024
+    compile_tpu(featurize_pallas, ((n_pad, D), F32), ((M, D), F32),
+                ((M, D), F32), ((M, D), U32), ((M, D), U32),
+                f=get_bucket_fn(bucket))
+
+
+@pytest.mark.parametrize("k", [None, 4])
+def test_fused_matvec_compiles(compile_tpu, k):
+    lay = (M, NB * BN)
+    beta = lay if k is None else (M, k, NB * BN)
+    compile_tpu(bin_fused_matvec_pallas, ((M, 2 * NB), I32),
+                ((M, 2 * NB), I32), ((M, 2 * NB), I32), (lay, I32),
+                (lay, F32), (beta, F32), block_n=BN, block_t=BT)
+
+
+@pytest.mark.parametrize("n, fits", [(1 << 20, True), ((1 << 20) + 1, False)])
+def test_fused_matvec_smem_boundary(compile_tpu, n, fits):
+    """One instance's fused schedule (three lists of 2·NB int32 visits,
+    NB = n/bn + B/bt) fits the SMEM budget of one call up to n = 2^20 at
+    B = default_table_size(n); one point more doubles B and is refused."""
+    nb = n // BN + default_table_size(n) // BT
+    shapes = 3 * [((1, 2 * nb), I32)] + [((1, nb * BN), I32),
+                                         ((1, nb * BN), F32),
+                                         ((1, nb * BN), F32)]
+    if fits:
+        compile_tpu(bin_fused_matvec_pallas, *shapes, block_n=BN, block_t=BT)
+    else:
+        with pytest.raises(ValueError, match="SMEM"):
+            compile_tpu(bin_fused_matvec_pallas, *shapes, block_n=BN,
+                        block_t=BT)
+
+
+def test_blocked_scatter_compiles(compile_tpu):
+    compile_tpu(bin_scatter_blocked_pallas, ((M, NB), I32), ((M, NB), I32),
+                ((M, NB * BN), I32), ((M, NB * BN), F32), num_tiles=TILES,
+                block_n=BN, block_t=BT)
+
+
+def test_blocked_gather_compiles(compile_tpu):
+    compile_tpu(bin_gather_blocked_pallas, ((M, NB), I32),
+                ((M, NB * BN), I32), ((M, B), F32), block_n=BN, block_t=BT)
+
+
+def test_cross_product_scatter_compiles(compile_tpu):
+    n_pad = -(-N // 1024) * 1024
+    compile_tpu(bin_scatter_pallas, ((M, n_pad), I32), ((M, n_pad), F32),
+                table_size=B)
+
+
+def test_cross_product_gather_compiles(compile_tpu):
+    compile_tpu(bin_gather_pallas, ((M, 1024), I32), ((M, B), F32))
+
+
+def test_route_pack_compiles(compile_tpu):
+    v = CELL_TILES + M * VB
+    compile_tpu(route_pack_pallas, ((v,), I32), ((v,), I32), ((v,), I32),
+                ((v,), I32), ((M, NB_LOC * BN), I32), ((M, NB_LOC * BN), F32),
+                num_cell_tiles=CELL_TILES, block_n=BN, block_t=BT)
+
+
+def test_route_unpack_compiles(compile_tpu):
+    compile_tpu(route_unpack_pallas, ((M, VB), I32), ((M, VB), I32),
+                ((M, VB), I32), ((M, NB_LOC * BN), I32),
+                ((M, NB_LOC * BN), F32), ((1, CELL_TILES * BT), F32),
+                block_n=BN, block_t=BT)
+
+
+def test_placement_on_tpu_picks_compiled_kernels(topo):
+    """Backend and interpret mode follow the devices a program is placed on:
+    a mesh of TPU devices gets the compiled pallas kernels even from a CPU
+    host, and asking for the interpreter there is refused."""
+    from repro.core.distributed import KRRStepConfig, _shard_operator
+    lsh = sample_lsh_params(jax.random.PRNGKey(0), 4, 3, GammaPDF(2.0, 1.0))
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    cfg = KRRStepConfig(m=4, table_size=512, lam=0.5, cg_iters=1)
+    op = _shard_operator(cfg, get_bucket_fn("rect"), lsh, mesh)
+    assert (op.backend, op.interpret) == ("pallas", False)
+    op = make_operator(lsh, get_bucket_fn("rect"), 512,
+                       platform=topo.devices[0].platform)
+    assert (op.backend, op.interpret) == ("pallas", False)
+    with pytest.raises(ValueError, match="interpret"):
+        make_operator(lsh, get_bucket_fn("rect"), 512, backend="pallas",
+                      interpret=True, platform=topo.devices[0].platform)
