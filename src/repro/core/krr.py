@@ -40,6 +40,11 @@ from .precond import (DEFAULT_NYSTROM_RANK, Preconditioner, identity_precond,
 Array = jnp.ndarray
 MatVec = Callable[[Array], Array]
 
+# The PCG loop's name in JAX's name stack.  ``cond`` and ``body`` are traced,
+# so every op of the loop carries it in its HLO metadata, even when the
+# while itself is dispatched eagerly.
+PCG_SCOPE = "wlsh.pcg"
+
 
 class CGResult(NamedTuple):
     x: Array
@@ -181,10 +186,12 @@ def pcg_solve(matvec: MatVec, b: Array, lam: float, *,
     hist = hist.at[state.it].set(jnp.sqrt(state.rs))
     chunk = int(checkpoint_every) if checkpoint_every > 0 else maxiter
 
+    @jax.named_scope(PCG_SCOPE)
     def cond(carry):
         steps, st, _ = carry
         return jnp.any(st.active) & (st.it < maxiter_a) & (steps < chunk)
 
+    @jax.named_scope(PCG_SCOPE)
     def body(carry):
         steps, st, hist = carry
         x, r, p, rs, rho, active, it, col_iters = st
@@ -365,9 +372,9 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
     lsh = sample_lsh_params(key, m, d, spec.pdf, spec.lengthscale)
     op = make_operator(lsh, get_bucket_fn(spec.bucket.name), table_size,
                        backend=backend, fused=fused, platform=platform_of(x))
-    with obs.span("fit.featurize", {"n": n, "m": m},
-                  to_histogram=obs.histogram(
-                      "fit_featurize_us", "featurize wall time per fit")):
+    # the fit.* spans time host work and dispatch (nothing here waits for
+    # the device): they name the host's share of a trace's idle gaps
+    with obs.span("fit.featurize", {"n": n, "m": m}):
         feats = op.featurize(x)
 
     # Prediction tables are always CountSketch (exact-mode key lookup for
@@ -375,9 +382,7 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
     # and O(1) per query — see DESIGN.md §3).  In table mode the same index
     # drives CG, so it is built exactly once (the CG closure closes over the
     # slot-blocked layout when fused — the sort runs once, not per iteration).
-    with obs.span("fit.build_index", {"mode": mode},
-                  to_histogram=obs.histogram(
-                      "fit_build_index_us", "index build wall time per fit")):
+    with obs.span("fit.build_index", {"mode": mode}):
         tidx = op.build_index(feats, mode="table",
                               blocked=fused and mode == "table")
         if mode == "exact":
@@ -410,9 +415,7 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
             if on_solve_checkpoint is not None:
                 on_solve_checkpoint(st)
 
-    with obs.span("fit.pcg_solve", {"precond": precond, "maxiter": maxiter},
-                  to_histogram=obs.histogram(
-                      "fit_pcg_solve_us", "PCG solve wall time per fit")):
+    with obs.span("fit.pcg_solve", {"precond": precond, "maxiter": maxiter}):
         res = pcg_solve(mv, y, lam, precond=pre, tol=tol, atol=atol,
                         maxiter=maxiter, state=state, checkpoint_every=every,
                         on_checkpoint=on_ck)

@@ -57,6 +57,16 @@ def build_exact_index(feats: Features) -> ExactIndex:
     return ExactIndex(perm=perm, seg_id=seg_id, weight=feats.weight)
 
 
+# Names of the table matvec's parts in JAX's name stack, shared by the
+# reference primitives below and the kernel-backed ops (kernels/binning):
+# the scatter/gather itself, and the moves around it (padding, gathers into
+# and out of a slot order, the coefficient product and instance mean).
+# Inside a traced program (the PCG loop's body) they reach the HLO metadata,
+# so a device trace tells layout time from kernel time.
+KERNEL_SCOPE = "wlsh.matvec.kernel"
+LAYOUT_SCOPE = "wlsh.matvec.layout"
+
+
 def _colwise(coeff: Array, v: Array) -> Array:
     """coeff ⊙ v for v of shape (n,) or (n, k) (coeff broadcast over RHS
     columns).  The single place the multi-RHS axis convention lives."""
@@ -432,11 +442,14 @@ def build_route_schedule(cell_lay: Array, *, num_cell_tiles: int,
 def table_loads(index: TableIndex, beta: Array) -> Array:
     """Bucket-load tables for all m instances: (m, B) for beta (n,), or
     (m, B, k) for a (n, k) RHS block (one scatter, k stacked columns)."""
-    contrib = jax.vmap(_colwise, in_axes=(0, None))(index.coeff, beta)
+    with jax.named_scope(LAYOUT_SCOPE):
+        contrib = jax.vmap(_colwise, in_axes=(0, None))(index.coeff, beta)
     m = index.slot.shape[0]
-    tables = jnp.zeros((m, index.table_size) + beta.shape[1:], contrib.dtype)
-    rows = jnp.arange(m, dtype=jnp.int32)[:, None]
-    return tables.at[rows, index.slot].add(contrib)
+    with jax.named_scope(KERNEL_SCOPE):
+        tables = jnp.zeros((m, index.table_size) + beta.shape[1:],
+                           contrib.dtype)
+        rows = jnp.arange(m, dtype=jnp.int32)[:, None]
+        return tables.at[rows, index.slot].add(contrib)
 
 
 def table_readout(index: TableIndex, tables: Array, *,
@@ -445,9 +458,12 @@ def table_readout(index: TableIndex, tables: Array, *,
     when ``average``, else the plain instance sum (distributed shards sum
     locally and divide by the global m after their model-axis psum).
     ``tables`` is (m, B) -> (n,) out, or (m, B, k) -> (n, k)."""
-    rows = jnp.arange(index.slot.shape[0], dtype=jnp.int32)[:, None]
-    vals = jax.vmap(_colwise)(index.coeff, tables[rows, index.slot])
-    return jnp.mean(vals, axis=0) if average else jnp.sum(vals, axis=0)
+    with jax.named_scope(KERNEL_SCOPE):
+        rows = jnp.arange(index.slot.shape[0], dtype=jnp.int32)[:, None]
+        loads = tables[rows, index.slot]
+    with jax.named_scope(LAYOUT_SCOPE):
+        vals = jax.vmap(_colwise)(index.coeff, loads)
+        return jnp.mean(vals, axis=0) if average else jnp.sum(vals, axis=0)
 
 
 def table_matvec(index: TableIndex, beta: Array) -> Array:
@@ -481,13 +497,17 @@ def table_matvec_fused(index: TableIndex, beta: Array, *,
     n = beta.shape[0]
 
     def one(perm, seg_id, coeff_sorted, seg_pt, coeff):
-        loads = jax.ops.segment_sum(_colwise(coeff_sorted, beta[perm]), seg_id,
-                                    num_segments=n)
-        return _colwise(coeff, loads[seg_pt])
+        with jax.named_scope(LAYOUT_SCOPE):
+            contrib = _colwise(coeff_sorted, beta[perm])
+        with jax.named_scope(KERNEL_SCOPE):
+            loads = jax.ops.segment_sum(contrib, seg_id, num_segments=n)
+        with jax.named_scope(LAYOUT_SCOPE):
+            return _colwise(coeff, loads[seg_pt])
 
     outs = jax.vmap(one)(lay.perm, lay.seg_id, lay.coeff_sorted, lay.seg_pt,
                          index.coeff)
-    return jnp.mean(outs, axis=0) if average else jnp.sum(outs, axis=0)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return jnp.mean(outs, axis=0) if average else jnp.sum(outs, axis=0)
 
 
 def table_kernel_matrix(index: TableIndex) -> Array:

@@ -54,6 +54,10 @@ columns.  The fused kernel keeps its VMEM table tile in that column form;
 the kernels whose tables live in HBM as lane-dense rows transpose one
 (k, bt) tile per visit.
 
+Every ``pallas_call`` carries a stable ``name`` (``wlsh_fused_matvec``,
+``wlsh_blocked_scatter``, ...): it is the kernel's op name on a profiler
+trace, so the trace reads by kernel whatever jitted function calls it.
+
 Scalar-prefetched visit lists live in SMEM, which holds 1 MiB on v5e.  A
 per-instance schedule larger than ``SMEM_SCHEDULE_BYTES`` runs as several
 calls over instance groups (``_grouped_call``), all writing one output
@@ -135,8 +139,8 @@ def _instance_groups(m: int, per_instance_bytes: int):
     return [(s, min(g, m - s)) for s in range(0, m, g)]
 
 
-def _grouped_call(body, sched, operands, specs, out_shape, *, interpret,
-                  scratch_shapes=()):
+def _grouped_call(body, sched, operands, specs, out_shape, *, name,
+                  interpret, scratch_shapes=()):
     """Run a per-instance visit-list kernel over the grid (m, V).
 
     ``sched`` are (m, V) int32 schedules, scalar-prefetched.  ``specs(s)``
@@ -144,7 +148,8 @@ def _grouped_call(body, sched, operands, specs, out_shape, *, interpret,
     index maps see the group-local instance i (which indexes the schedule
     rows) and address instance i + s of the operands.  One pallas_call runs
     per group; each writes its instances' rows of the single output buffer,
-    which later calls take as an aliased input the body never reads."""
+    which later calls take as an aliased input the body never reads.
+    ``name`` is every call's kernel name on the device trace."""
     m, n_vis = sched[0].shape
     out = None
     for s, g in _instance_groups(m, 4 * len(sched) * n_vis):
@@ -168,6 +173,7 @@ def _grouped_call(body, sched, operands, specs, out_shape, *, interpret,
             out_shape=out_shape,
             input_output_aliases=aliases,
             interpret=interpret,
+            name=name,
         )(*args)
     return out
 
@@ -210,6 +216,7 @@ def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool,
         out_specs=pl.BlockSpec((None, 1, bt), lambda i, t, j: (i, 0, t)),
         out_shape=jax.ShapeDtypeStruct((m, 1, table_size), jnp.float32),
         interpret=interpret,
+        name="wlsh_table_scatter",
     )(_with_row_axis(slot), _with_row_axis(contrib.astype(jnp.float32)))[:, 0]
 
 
@@ -281,7 +288,8 @@ def bin_fused_matvec_pallas(v_block, v_tile, v_phase, slot_lay, coeff_lay,
     out = _grouped_call(
         _fused_body, (v_block, v_tile, v_phase),
         (_with_row_axis(slot_lay), _with_row_axis(coeff_lay), beta3), specs,
-        jax.ShapeDtypeStruct(beta3.shape, jnp.float32), interpret=interpret,
+        jax.ShapeDtypeStruct(beta3.shape, jnp.float32),
+        name="wlsh_fused_matvec", interpret=interpret,
         scratch_shapes=[pltpu.VMEM((block_t, k), jnp.float32)])
     return out[:, 0] if beta_lay.ndim == 2 else out
 
@@ -354,7 +362,7 @@ def bin_scatter_blocked_pallas(vs_block, vs_tile, slot_lay, contrib_lay, *,
         _scatter_blocked_body, (vs_block, vs_tile),
         (_with_row_axis(slot_lay), contrib3), specs,
         jax.ShapeDtypeStruct((m, k, num_tiles * block_t), jnp.float32),
-        interpret=interpret)
+        name="wlsh_blocked_scatter", interpret=interpret)
     return out[:, 0] if contrib_lay.ndim == 2 else out
 
 
@@ -391,7 +399,7 @@ def bin_gather_blocked_pallas(vg_tile, slot_lay, tables, *, block_n: int,
         _gather_blocked_body, (vg_tile,), (_with_row_axis(slot_lay), tables3),
         specs,
         jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
-        interpret=interpret)
+        name="wlsh_blocked_gather", interpret=interpret)
     return out[:, 0] if tables.ndim == 2 else out
 
 
@@ -495,6 +503,7 @@ def route_pack_pallas(p_inst, p_block, p_tile, p_flag, cell_lay, contrib_lay,
             out_shape=out_shape,
             input_output_aliases={6: 0},
             interpret=interpret,
+            name="wlsh_route_pack",
         )(*sched, _with_row_axis(cell_lay), contrib3, out)
     return out
 
@@ -529,7 +538,7 @@ def route_unpack_pallas(u_block, u_tile, u_flag, cell_lay, coeff_lay, back, *,
         (_with_row_axis(cell_lay), _with_row_axis(coeff_lay),
          back.astype(jnp.float32)), specs,
         jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
-        interpret=interpret)
+        name="wlsh_route_unpack", interpret=interpret)
     return out[:, 0] if k == 1 else out
 
 
@@ -552,4 +561,5 @@ def bin_gather_pallas(slot, tables, *, interpret: bool,
         out_specs=point_spec,
         out_shape=jax.ShapeDtypeStruct((m, 1, n), jnp.float32),
         interpret=interpret,
+        name="wlsh_readout_gather",
     )(_with_row_axis(slot), _with_row_axis(tables.astype(jnp.float32)))[:, 0]

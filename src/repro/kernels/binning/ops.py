@@ -22,12 +22,18 @@ exact).  Callers never see padding — outputs are trimmed to logical shapes.
 ``interpret`` is required: the caller (``core.operator``) decides it from
 the platform the program is placed on — the Pallas interpreter on CPU,
 compiled kernels on TPU.
+
+Each op names its parts in JAX's name stack, as the reference table
+primitives do: the kernel call under ``KERNEL_SCOPE``, the jnp moves around
+it (padding, the gathers into and out of the slot layout, the instance
+mean) under ``LAYOUT_SCOPE``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from ...core.wlsh import TableIndex
+from ...core.wlsh import KERNEL_SCOPE, LAYOUT_SCOPE, TableIndex
 from .kernel import (BLOCK_N, BLOCK_T, bin_fused_matvec_pallas,
                      bin_gather_blocked_pallas, bin_gather_pallas,
                      bin_scatter_blocked_pallas, bin_scatter_pallas)
@@ -75,13 +81,19 @@ def bin_loads_blocked_op(index: TableIndex, beta, *, interpret: bool):
                          "with the pallas group; build it with "
                          "build_blocked_layout(parts='pallas'|'both') / a "
                          "pallas-backend build_index(blocked=True)")
-    beta_lay = _beta_to_layout(lay, beta)                    # (m,[ k,] L)
-    coeff = lay.coeff_lay if beta.ndim == 1 else lay.coeff_lay[:, None, :]
-    tables = bin_scatter_blocked_pallas(
-        lay.vs_block, lay.vs_tile, lay.slot_lay, coeff * beta_lay,
-        num_tiles=lay.num_tiles, block_n=lay.block_n, block_t=lay.block_t,
-        interpret=interpret)[..., :index.table_size]
-    return jnp.swapaxes(tables, 1, 2) if beta.ndim == 2 else tables
+    with jax.named_scope(LAYOUT_SCOPE):
+        beta_lay = _beta_to_layout(lay, beta)                # (m,[ k,] L)
+        coeff = lay.coeff_lay if beta.ndim == 1 \
+            else lay.coeff_lay[:, None, :]
+        contrib = coeff * beta_lay
+    with jax.named_scope(KERNEL_SCOPE):
+        tables = bin_scatter_blocked_pallas(
+            lay.vs_block, lay.vs_tile, lay.slot_lay, contrib,
+            num_tiles=lay.num_tiles, block_n=lay.block_n,
+            block_t=lay.block_t, interpret=interpret)
+    with jax.named_scope(LAYOUT_SCOPE):
+        tables = tables[..., :index.table_size]
+        return jnp.swapaxes(tables, 1, 2) if beta.ndim == 2 else tables
 
 
 def bin_readout_blocked_op(index: TableIndex, tables, *, interpret: bool,
@@ -97,19 +109,23 @@ def bin_readout_blocked_op(index: TableIndex, tables, *, interpret: bool,
                          "pallas-backend build_index(blocked=True)")
     multi = tables.ndim == 3
     bp = lay.num_tiles * lay.block_t
-    t = jnp.swapaxes(tables, 1, 2) if multi else tables      # (m,[ k,] B)
-    t = jnp.pad(t.astype(jnp.float32),
-                ((0, 0),) * (t.ndim - 1) + ((0, bp - index.table_size),))
-    out_lay = bin_gather_blocked_pallas(
-        lay.vg_tile, lay.slot_lay, t, block_n=lay.block_n,
-        block_t=lay.block_t, interpret=interpret)
-    rows = jnp.arange(index.slot.shape[0], dtype=jnp.int32)[:, None]
-    if multi:
-        vals = jnp.swapaxes(out_lay, 1, 2)[rows, lay.inv_pos]  # (m, n, k)
-        signed = vals * index.coeff[:, :, None]
-    else:
-        signed = out_lay[rows, lay.inv_pos] * index.coeff      # (m, n)
-    return jnp.mean(signed, axis=0) if average else jnp.sum(signed, axis=0)
+    with jax.named_scope(LAYOUT_SCOPE):
+        t = jnp.swapaxes(tables, 1, 2) if multi else tables  # (m,[ k,] B)
+        t = jnp.pad(t.astype(jnp.float32),
+                    ((0, 0),) * (t.ndim - 1) + ((0, bp - index.table_size),))
+    with jax.named_scope(KERNEL_SCOPE):
+        out_lay = bin_gather_blocked_pallas(
+            lay.vg_tile, lay.slot_lay, t, block_n=lay.block_n,
+            block_t=lay.block_t, interpret=interpret)
+    with jax.named_scope(LAYOUT_SCOPE):
+        rows = jnp.arange(index.slot.shape[0], dtype=jnp.int32)[:, None]
+        if multi:
+            vals = jnp.swapaxes(out_lay, 1, 2)[rows, lay.inv_pos]  # (m, n, k)
+            signed = vals * index.coeff[:, :, None]
+        else:
+            signed = out_lay[rows, lay.inv_pos] * index.coeff      # (m, n)
+        return jnp.mean(signed, axis=0) if average \
+            else jnp.sum(signed, axis=0)
 
 
 def bin_loads_op(index: TableIndex, beta, *, interpret: bool,
@@ -128,16 +144,20 @@ def bin_loads_op(index: TableIndex, beta, *, interpret: bool,
                              block_n=block_n, block_t=block_t)
                 for j in range(beta.shape[1])]
         return jnp.stack(cols, axis=-1)
-    contrib = (beta[None, :] * index.coeff).astype(jnp.float32)
     bn, bt = _block_sizes(index.slot.shape[1], index.table_size, block_n,
                           block_t)
-    # pad points into slot 0 with zero contribution (cannot perturb loads)
-    slot_p, _ = _pad_points(index.slot, bn, value=0)
-    contrib_p, _ = _pad_points(contrib, bn, value=0.0)
     bp = _round_up(index.table_size, bt)
-    tables = bin_scatter_pallas(slot_p, contrib_p, table_size=bp,
-                                interpret=interpret, block_n=bn, block_t=bt)
-    return tables[:, :index.table_size]
+    with jax.named_scope(LAYOUT_SCOPE):
+        contrib = (beta[None, :] * index.coeff).astype(jnp.float32)
+        # pad points into slot 0 with zero contribution (cannot perturb loads)
+        slot_p, _ = _pad_points(index.slot, bn, value=0)
+        contrib_p, _ = _pad_points(contrib, bn, value=0.0)
+    with jax.named_scope(KERNEL_SCOPE):
+        tables = bin_scatter_pallas(slot_p, contrib_p, table_size=bp,
+                                    interpret=interpret, block_n=bn,
+                                    block_t=bt)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return tables[:, :index.table_size]
 
 
 def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
@@ -162,14 +182,18 @@ def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
         return jnp.stack(cols, axis=-1)
     n = index.slot.shape[1]
     bn, bt = _block_sizes(n, index.table_size, block_n, block_t)
-    slot_p, _ = _pad_points(index.slot, bn, value=0)
     bp = _round_up(index.table_size, bt)
-    tables_p = jnp.pad(tables.astype(jnp.float32),
-                       ((0, 0), (0, bp - index.table_size)))
-    vals = bin_gather_pallas(slot_p, tables_p, interpret=interpret,
-                             block_n=bn, block_t=bt)[:, :n]
-    signed = vals * index.coeff
-    return jnp.mean(signed, axis=0) if average else jnp.sum(signed, axis=0)
+    with jax.named_scope(LAYOUT_SCOPE):
+        slot_p, _ = _pad_points(index.slot, bn, value=0)
+        tables_p = jnp.pad(tables.astype(jnp.float32),
+                           ((0, 0), (0, bp - index.table_size)))
+    with jax.named_scope(KERNEL_SCOPE):
+        vals = bin_gather_pallas(slot_p, tables_p, interpret=interpret,
+                                 block_n=bn, block_t=bt)
+    with jax.named_scope(LAYOUT_SCOPE):
+        signed = vals[:, :n] * index.coeff
+        return jnp.mean(signed, axis=0) if average \
+            else jnp.sum(signed, axis=0)
 
 
 def table_matvec_op(index: TableIndex, beta, *, interpret: bool):
@@ -199,19 +223,22 @@ def bin_fused_matvec_op(index: TableIndex, beta, *, interpret: bool,
                          "build_index(blocked=True)")
     m = index.slot.shape[0]
     multi = beta.ndim == 2
-    pad = jnp.zeros((1,) + beta.shape[1:], jnp.float32)
-    beta_pad = jnp.concatenate([jnp.asarray(beta, jnp.float32), pad])
-    beta_lay = beta_pad[lay.src]               # (m, L) | (m, L, k)
-    if multi:
-        beta_lay = jnp.swapaxes(beta_lay, 1, 2)              # (m, k, L)
-    out_lay = bin_fused_matvec_pallas(
-        lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay, lay.coeff_lay,
-        beta_lay, block_n=lay.block_n, block_t=lay.block_t,
-        interpret=interpret)
-    rows = jnp.arange(m, dtype=jnp.int32)[:, None]
-    if multi:
-        # (m, k, L) -> (m, n, k), coeff already applied inside the kernel
-        vals = jnp.swapaxes(out_lay, 1, 2)[rows, lay.inv_pos]
-    else:
-        vals = out_lay[rows, lay.inv_pos]      # (m, n)
-    return jnp.mean(vals, axis=0) if average else jnp.sum(vals, axis=0)
+    with jax.named_scope(LAYOUT_SCOPE):
+        pad = jnp.zeros((1,) + beta.shape[1:], jnp.float32)
+        beta_pad = jnp.concatenate([jnp.asarray(beta, jnp.float32), pad])
+        beta_lay = beta_pad[lay.src]           # (m, L) | (m, L, k)
+        if multi:
+            beta_lay = jnp.swapaxes(beta_lay, 1, 2)          # (m, k, L)
+    with jax.named_scope(KERNEL_SCOPE):
+        out_lay = bin_fused_matvec_pallas(
+            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
+            lay.coeff_lay, beta_lay, block_n=lay.block_n,
+            block_t=lay.block_t, interpret=interpret)
+    with jax.named_scope(LAYOUT_SCOPE):
+        rows = jnp.arange(m, dtype=jnp.int32)[:, None]
+        if multi:
+            # (m, k, L) -> (m, n, k), coeff already applied inside the kernel
+            vals = jnp.swapaxes(out_lay, 1, 2)[rows, lay.inv_pos]
+        else:
+            vals = out_lay[rows, lay.inv_pos]  # (m, n)
+        return jnp.mean(vals, axis=0) if average else jnp.sum(vals, axis=0)

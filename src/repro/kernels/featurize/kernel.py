@@ -136,6 +136,7 @@ def featurize_pallas(x, w, z, r1, r2, *, f: BucketFn, interpret: bool,
         out_specs=[out_spec, out_spec, out_spec, out_spec],
         out_shape=out_shapes,
         interpret=interpret,
+        name="wlsh_featurize",
     )(xt, wp, zp, r1p, r2p)
     as_u32 = functools.partial(jax.lax.bitcast_convert_type,
                                new_dtype=jnp.uint32)

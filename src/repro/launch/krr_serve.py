@@ -49,7 +49,7 @@ _REQUIRED_SERIES = (
     "serve_cache_misses_total", "serve_cache_entries",
     "serve_models_loaded_total", "serve_batcher_requests_total",
     "serve_batcher_served_total", "serve_queue_wait_us", "serve_batch_size",
-    "serve_batch_predict_us", "serve_queue_depth_hwm",
+    "serve_batch_fill_us", "serve_batch_predict_us", "serve_queue_depth_hwm",
 )
 # extra series that must exist under --mesh (registered per shard at load,
 # so an alerting rule can tell "zero overflow" from "not sharded")

@@ -29,8 +29,6 @@ import threading
 from collections import deque
 from time import perf_counter
 
-from . import registry as _registry
-
 _TRACING = True          # span timing + sample collection
 _JAX_ANNOTATIONS = False  # also open jax.profiler.TraceAnnotation regions
 
@@ -324,8 +322,6 @@ def start_trace(trace_dir: str) -> None:
     jax.profiler.start_trace(trace_dir)
     _trace_dir = trace_dir
     set_jax_annotations(True)
-    _registry.counter(
-        "trace_sessions_total", "profiler trace captures started").inc()
 
 
 def stop_trace() -> str | None:

@@ -10,6 +10,10 @@ State machine (one worker thread):
                 request's future (or fail them all with the raised
                 exception) -> IDLE
 
+On a profiler trace IDLE is the ``serve.await_request`` annotation, FILLING
+the ``serve.batch_fill`` timer (``serve_batch_fill_us``: what to read when
+tuning ``max_wait_us``) and predict_fn the ``serve.batch_predict`` timer.
+
 max_batch bounds tail latency under load (a full batch flushes immediately);
 max_wait_us bounds it when idle (a lone request waits at most one deadline).
 Each request costs its queue wait plus a 1/batch share of one warm-path call
@@ -119,6 +123,9 @@ class MicroBatcher:
             "serve_queue_wait_us", "request wait from submit to flush").labels()
         self._m_predict = obs.histogram(
             "serve_batch_predict_us", "predict_fn wall time per batch").labels()
+        self._m_fill = obs.histogram(
+            "serve_batch_fill_us",
+            "first request dequeued to dispatch, per batch").labels()
         self._m_batch_size = obs.histogram(
             "serve_batch_size", "rows coalesced per flushed batch",
             buckets=obs.COUNT_BUCKETS).labels()
@@ -135,9 +142,11 @@ class MicroBatcher:
             "requests expired in queue before predict").labels()
         self._m_hwm = obs.gauge(
             "serve_queue_depth_hwm", "high-water mark of the request queue").labels()
-        # flat pre-bound timer: one per flushed batch on the worker thread
+        # flat pre-bound timers: one of each per batch on the worker thread
         self._t_batch = obs.timer("serve.batch_predict",
                                   to_histogram=self._m_predict)
+        self._t_fill = obs.timer("serve.batch_fill",
+                                 to_histogram=self._m_fill)
         self._closed = False
         self._crashed: BaseException | None = None
         self._inflight: list[_Request] | None = None
@@ -230,31 +239,34 @@ class MicroBatcher:
     def _run(self) -> None:
         try:
             while True:
-                req = self._queue.get()             # IDLE
+                # names the server's idle time on a profiler trace
+                with obs.annotation("serve.await_request"):
+                    req = self._queue.get()         # IDLE
                 if req is None:
                     return
                 batch = [req]                       # FILLING
-                deadline = time.perf_counter() + self.max_wait_s
                 stop = False
-                while len(batch) < self.max_batch:
-                    try:
-                        # anything ALREADY queued joins the batch at once —
-                        # under backlog the deadline never delays (or
-                        # starves) coalescing, it only bounds the wait for
-                        # new arrivals
-                        nxt = self._queue.get_nowait()
-                    except queue.Empty:
-                        timeout = deadline - time.perf_counter()
-                        if timeout <= 0:
-                            break
+                with self._t_fill():
+                    deadline = time.perf_counter() + self.max_wait_s
+                    while len(batch) < self.max_batch:
                         try:
-                            nxt = self._queue.get(timeout=timeout)
+                            # anything ALREADY queued joins the batch at
+                            # once — under backlog the deadline never
+                            # delays (or starves) coalescing, it only
+                            # bounds the wait for new arrivals
+                            nxt = self._queue.get_nowait()
                         except queue.Empty:
+                            timeout = deadline - time.perf_counter()
+                            if timeout <= 0:
+                                break
+                            try:
+                                nxt = self._queue.get(timeout=timeout)
+                            except queue.Empty:
+                                break
+                        if nxt is None:
+                            stop = True
                             break
-                    if nxt is None:
-                        stop = True
-                        break
-                    batch.append(nxt)
+                        batch.append(nxt)
                 self._dispatch(batch)               # FLUSH -> IDLE
                 if stop:
                     return

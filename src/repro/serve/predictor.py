@@ -18,7 +18,6 @@ inserted.  Hits are exact — the cache stores the warm path's own output.
 from __future__ import annotations
 
 import threading
-from time import perf_counter
 from typing import NamedTuple
 
 import jax
@@ -121,6 +120,8 @@ class Predictor:
                                     to_histogram=self._m_predict_us)
         self._t_warm = obs.timer("serve.warm_compute",
                                  to_histogram=self._m_warm_us)
+        self._t_probe = obs.timer("serve.cache_probe",
+                                  to_histogram=self._m_probe_us)
 
     # -- model hosting ------------------------------------------------------
 
@@ -263,10 +264,9 @@ class Predictor:
             out = self._predict_warm(hosted, x)
             return out[0] if single else out
 
-        t0 = perf_counter()
-        keys = self._bucket_keys(hosted, x)
-        found = hosted.cache.get_many(keys)
-        self._m_probe_us.observe((perf_counter() - t0) * 1e6)
+        with self._t_probe():
+            keys = self._bucket_keys(hosted, x)
+            found = hosted.cache.get_many(keys)
         if single and found[0] is not None:       # all-hit serving fast path
             self._m_hits.inc()
             v = found[0]

@@ -37,7 +37,6 @@ StepStats plumbing) next to the attached batcher's queue depth.
 from __future__ import annotations
 
 import threading
-from time import perf_counter
 from typing import NamedTuple
 
 import jax
@@ -160,6 +159,8 @@ class ShardedPredictor:
                                     to_histogram=self._m_predict_us)
         self._t_warm = obs.timer("serve.warm_compute",
                                  to_histogram=self._m_warm_us)
+        self._t_probe = obs.timer("serve.cache_probe",
+                                  to_histogram=self._m_probe_us)
 
     # -- model hosting ------------------------------------------------------
 
@@ -374,10 +375,9 @@ class ShardedPredictor:
             out = self._predict_warm(hosted, x)
             return out[0] if single else out
 
-        t0 = perf_counter()
-        keys = self._sharded_keys(hosted, x)
-        found = hosted.cache.get_many(keys)
-        self._m_probe_us.observe((perf_counter() - t0) * 1e6)
+        with self._t_probe():
+            keys = self._sharded_keys(hosted, x)
+            found = hosted.cache.get_many(keys)
         miss = [i for i, v in enumerate(found) if v is None]
         if len(found) > len(miss):
             self._m_hits.inc(len(found) - len(miss))

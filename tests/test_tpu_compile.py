@@ -6,7 +6,9 @@ Nothing runs: the TPU compiler is asked about a described v5e chip, so these
 tests catch what the Pallas interpreter cannot see — block shapes the chip's
 tiling refuses, unsupported ops, scalar memory over its size.  Each test
 also checks that the compiled program holds a Mosaic kernel
-(``tpu_custom_call``), so an interpret-mode lowering cannot pass for one.
+(``tpu_custom_call``), so an interpret-mode lowering cannot pass for one;
+the kernel tests, that it carries its stable name (its op name on a device
+trace).
 
 The topology is described inside a module fixture (never at import time):
 only the worker that runs this file loads the TPU library.
@@ -87,18 +89,20 @@ I32, U32, F32 = jnp.int32, jnp.uint32, jnp.float32
 @pytest.mark.parametrize("bucket", ["rect", "smooth"])
 def test_featurize_compiles(compile_tpu, bucket):
     n_pad = -(-N // 1024) * 1024
-    compile_tpu(featurize_pallas, ((n_pad, D), F32), ((M, D), F32),
-                ((M, D), F32), ((M, D), U32), ((M, D), U32),
-                f=get_bucket_fn(bucket))
+    text = compile_tpu(featurize_pallas, ((n_pad, D), F32), ((M, D), F32),
+                       ((M, D), F32), ((M, D), U32), ((M, D), U32),
+                       f=get_bucket_fn(bucket))
+    assert "%wlsh_featurize" in text
 
 
 @pytest.mark.parametrize("k", [None, 4])
 def test_fused_matvec_compiles(compile_tpu, k):
     lay = (M, NB * BN)
     beta = lay if k is None else (M, k, NB * BN)
-    compile_tpu(bin_fused_matvec_pallas, ((M, 2 * NB), I32),
-                ((M, 2 * NB), I32), ((M, 2 * NB), I32), (lay, I32),
-                (lay, F32), (beta, F32), block_n=BN, block_t=BT)
+    text = compile_tpu(bin_fused_matvec_pallas, ((M, 2 * NB), I32),
+                       ((M, 2 * NB), I32), ((M, 2 * NB), I32), (lay, I32),
+                       (lay, F32), (beta, F32), block_n=BN, block_t=BT)
+    assert "%wlsh_fused_matvec" in text
 
 
 @pytest.mark.parametrize("n, fits", [(1 << 20, True), ((1 << 20) + 1, False)])
@@ -119,38 +123,47 @@ def test_fused_matvec_smem_boundary(compile_tpu, n, fits):
 
 
 def test_blocked_scatter_compiles(compile_tpu):
-    compile_tpu(bin_scatter_blocked_pallas, ((M, NB), I32), ((M, NB), I32),
-                ((M, NB * BN), I32), ((M, NB * BN), F32), num_tiles=TILES,
-                block_n=BN, block_t=BT)
+    text = compile_tpu(bin_scatter_blocked_pallas, ((M, NB), I32),
+                       ((M, NB), I32), ((M, NB * BN), I32),
+                       ((M, NB * BN), F32), num_tiles=TILES, block_n=BN,
+                       block_t=BT)
+    assert "%wlsh_blocked_scatter" in text
 
 
 def test_blocked_gather_compiles(compile_tpu):
-    compile_tpu(bin_gather_blocked_pallas, ((M, NB), I32),
-                ((M, NB * BN), I32), ((M, B), F32), block_n=BN, block_t=BT)
+    text = compile_tpu(bin_gather_blocked_pallas, ((M, NB), I32),
+                       ((M, NB * BN), I32), ((M, B), F32), block_n=BN,
+                       block_t=BT)
+    assert "%wlsh_blocked_gather" in text
 
 
 def test_cross_product_scatter_compiles(compile_tpu):
     n_pad = -(-N // 1024) * 1024
-    compile_tpu(bin_scatter_pallas, ((M, n_pad), I32), ((M, n_pad), F32),
-                table_size=B)
+    text = compile_tpu(bin_scatter_pallas, ((M, n_pad), I32),
+                       ((M, n_pad), F32), table_size=B)
+    assert "%wlsh_table_scatter" in text
 
 
 def test_cross_product_gather_compiles(compile_tpu):
-    compile_tpu(bin_gather_pallas, ((M, 1024), I32), ((M, B), F32))
+    text = compile_tpu(bin_gather_pallas, ((M, 1024), I32), ((M, B), F32))
+    assert "%wlsh_readout_gather" in text
 
 
 def test_route_pack_compiles(compile_tpu):
     v = CELL_TILES + M * VB
-    compile_tpu(route_pack_pallas, ((v,), I32), ((v,), I32), ((v,), I32),
-                ((v,), I32), ((M, NB_LOC * BN), I32), ((M, NB_LOC * BN), F32),
-                num_cell_tiles=CELL_TILES, block_n=BN, block_t=BT)
+    text = compile_tpu(route_pack_pallas, ((v,), I32), ((v,), I32),
+                       ((v,), I32), ((v,), I32), ((M, NB_LOC * BN), I32),
+                       ((M, NB_LOC * BN), F32), num_cell_tiles=CELL_TILES,
+                       block_n=BN, block_t=BT)
+    assert "%wlsh_route_pack" in text
 
 
 def test_route_unpack_compiles(compile_tpu):
-    compile_tpu(route_unpack_pallas, ((M, VB), I32), ((M, VB), I32),
-                ((M, VB), I32), ((M, NB_LOC * BN), I32),
-                ((M, NB_LOC * BN), F32), ((1, CELL_TILES * BT), F32),
-                block_n=BN, block_t=BT)
+    text = compile_tpu(route_unpack_pallas, ((M, VB), I32), ((M, VB), I32),
+                       ((M, VB), I32), ((M, NB_LOC * BN), I32),
+                       ((M, NB_LOC * BN), F32), ((1, CELL_TILES * BT), F32),
+                       block_n=BN, block_t=BT)
+    assert "%wlsh_route_unpack" in text
 
 
 def test_placement_on_tpu_picks_compiled_kernels(topo):
