@@ -18,8 +18,9 @@ B = default_table_size(n), rect bucket:
   (the formulation the select-and-reduce replaced), the reference sorted
   segment-sum, and the blocked split scatter + gather;
 - the readout of 1024 query points from (m, B) tables: the cross-product
-  gather kernel (the serving path), its MXU variant, and XLA's row gather
-  ``tables[s, slot]``.
+  gather kernel, its MXU variant, XLA's row gather ``tables[s, slot]``, and
+  the serving readout (``bin_readout_op`` on the layout-less query index:
+  that row gather, the coefficients and the instance mean).
 
 Variants are checked against the kernel they stand in for and the largest
 difference is printed.  Times from a CPU run say nothing about a device.
@@ -192,8 +193,10 @@ def main(argv=None) -> dict:
            probe("xla row gather 1024 q",
                  jax.jit(lambda s, t: jnp.take_along_axis(t, s, axis=1)),
                  q.slot, tables), vals)
-    differ("serving readout vs cross gather",
-           bin_readout_op(q, tables, interpret=interpret),
+    differ("serving readout (row gather) vs cross gather",
+           probe("serving readout (row gather) 1024 q",
+                 jax.jit(lambda t: bin_readout_op(q, t, interpret=interpret)),
+                 tables),
            jnp.mean(vals * q.coeff, axis=0))
     if args.json:
         with open(args.json, "w") as fh:

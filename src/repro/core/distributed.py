@@ -297,10 +297,10 @@ def make_krr_predict(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
 
     The index is built with the same ``want_blocked``/``local_fused`` logic
     as ``make_krr_step`` — a pallas-backend predict gathers through the
-    visit-list kernels off the slot-blocked layout instead of falling back
-    to the cross-product gather the train step abandoned (the old
-    ``blocked=False`` hardcode).  Reference-backend prediction still skips
-    the layout: its readout never consults it, so the sort would be wasted.
+    visit-list kernels off the slot-blocked layout.  A layout-less index
+    would read its loads by a direct row gather (``bin_readout_op``), which
+    needs no slot sort.  Reference-backend prediction skips the layout: its
+    readout never consults it, so the sort would be wasted.
     """
     n_data = _data_shard_count(mesh, cfg)
     local_fused = cfg.fused and n_data == 1

@@ -6,7 +6,8 @@ These are the kernel-backed equivalents of the reference table primitives in
 * ``bin_loads_op``   ~ ``table_loads``   — scatter signed, weighted beta into
   the (m, B) CountSketch tables.
 * ``bin_readout_op`` ~ ``table_readout`` — gather every point's bucket load
-  back out and combine over instances.
+  back out and combine over instances (the visit-list kernel when the index
+  carries the slot-blocked layout, else ``table_readout``'s row gather).
 * ``table_matvec_op`` ~ ``table_matvec`` — the composition of the two (the
   *split* path: the (m, B) table round-trips through HBM between the calls,
   which is what makes it psum-able in the distributed step).
@@ -33,10 +34,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.wlsh import KERNEL_SCOPE, LAYOUT_SCOPE, TableIndex
+from ...core.wlsh import (KERNEL_SCOPE, LAYOUT_SCOPE, TableIndex,
+                          table_readout)
 from .kernel import (BLOCK_N, BLOCK_T, bin_fused_matvec_pallas,
-                     bin_gather_blocked_pallas, bin_gather_pallas,
-                     bin_scatter_blocked_pallas, bin_scatter_pallas)
+                     bin_gather_blocked_pallas, bin_scatter_blocked_pallas,
+                     bin_scatter_pallas)
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -161,39 +163,20 @@ def bin_loads_op(index: TableIndex, beta, *, interpret: bool,
 
 
 def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
-                   average: bool = True, block_n: int = BLOCK_N,
-                   block_t: int = BLOCK_T):
+                   average: bool = True):
     """Kernel-backed ``table_readout``: per-point loads combined over the m
     instances (mean when ``average``, else sum — the distributed path sums
     locally and divides by the global m after its psum).  ``tables`` is
     (m, B) -> (n,) out, or (m, B, k) -> (n, k).  An index carrying the
     slot-blocked layout takes the visit-list gather
-    (``bin_readout_blocked_op``) at the layout's own geometry
-    (``block_n``/``block_t`` here shape only the cross-product fallback);
-    otherwise the cross-product kernel runs per column."""
+    (``bin_readout_blocked_op``) at the layout's own geometry; otherwise
+    each point's load is read by a direct row gather ``tables[s, slot]``
+    (``table_readout``): it reads the m·n addressed entries (times k) and
+    neither copies nor scans the (m, B) table."""
     if _split_layout(index) is not None:
         return bin_readout_blocked_op(index, tables, average=average,
                                       interpret=interpret)
-    if tables.ndim == 3:
-        cols = [bin_readout_op(index, tables[..., j], average=average,
-                               interpret=interpret, block_n=block_n,
-                               block_t=block_t)
-                for j in range(tables.shape[-1])]
-        return jnp.stack(cols, axis=-1)
-    n = index.slot.shape[1]
-    bn, bt = _block_sizes(n, index.table_size, block_n, block_t)
-    bp = _round_up(index.table_size, bt)
-    with jax.named_scope(LAYOUT_SCOPE):
-        slot_p, _ = _pad_points(index.slot, bn, value=0)
-        tables_p = jnp.pad(tables.astype(jnp.float32),
-                           ((0, 0), (0, bp - index.table_size)))
-    with jax.named_scope(KERNEL_SCOPE):
-        vals = bin_gather_pallas(slot_p, tables_p, interpret=interpret,
-                                 block_n=bn, block_t=bt)
-    with jax.named_scope(LAYOUT_SCOPE):
-        signed = vals[:, :n] * index.coeff
-        return jnp.mean(signed, axis=0) if average \
-            else jnp.sum(signed, axis=0)
+    return table_readout(index, tables, average=average)
 
 
 def table_matvec_op(index: TableIndex, beta, *, interpret: bool):

@@ -68,8 +68,35 @@ def test_table_tile_padding_path():
     assert tk.shape == tr.shape
     np.testing.assert_allclose(tk, tr, atol=1e-4)
     np.testing.assert_allclose(
-        bin_readout_op(idx, tr, interpret=True, block_t=384),
+        bin_readout_op(idx, tr, interpret=True),
         table_readout(idx, tr), atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [None, 3])
+@pytest.mark.parametrize("average", [True, False])
+def test_layoutless_readout_is_the_row_gather(k, average):
+    """An index without the slot-blocked layout (the serving path's
+    ``featurize_buckets``) reads its loads by ``table_readout``'s row gather,
+    bitwise, on (m, B) and (m, B, k) tables, at an n off the 128-point block
+    and a table_size off the 512-slot tile; no Pallas kernel is traced."""
+    from repro.core.wlsh import table_loads, table_readout
+    from repro.kernels.binning.ops import bin_readout_op
+    n, table_size = 200, 256
+    x, beta, _, pal = _ops(jax.random.PRNGKey(14), n, 3, 4, table_size)
+    if k is not None:
+        beta = jax.random.normal(jax.random.PRNGKey(15), (n, k))
+    idx = pal.featurize_buckets(x)
+    assert idx.blocked is None
+    tables = table_loads(idx, beta)
+
+    def readout(t):
+        return bin_readout_op(idx, t, interpret=True, average=average)
+
+    got = readout(tables)
+    want = table_readout(idx, tables, average=average)
+    assert got.shape == want.shape == beta.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert "pallas_call" not in str(jax.make_jaxpr(readout)(tables))
 
 
 def test_predict_batched_streams_fixed_blocks():

@@ -1,6 +1,6 @@
-"""Compile every Pallas kernel of kernels/featurize and kernels/binning for a
-TPU v5e, at the width of the paper's Forest Cover fit (d=54, n=500,000,
-m=64, B=2^21), with ``interpret=False``.
+"""Compile every Pallas kernel of kernels/featurize and kernels/binning, and
+the serving program around them, for a TPU v5e, at the width of the paper's
+Forest Cover fit (d=54, n=500,000, m=64, B=2^21), with ``interpret=False``.
 
 Nothing runs: the TPU compiler is asked about a described v5e chip, so these
 tests catch what the Pallas interpreter cannot see — block shapes the chip's
@@ -14,6 +14,7 @@ The topology is described inside a module fixture (never at import time):
 only the worker that runs this file loads the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro import hlo_analysis
 from repro.core import get_bucket_fn, make_operator, sample_lsh_params
+from repro.core.krr import WLSHKRRModel
 from repro.core.lsh import GammaPDF
 from repro.core.operator import default_table_size
 from repro.kernels.binning import (bin_fused_matvec_pallas,
@@ -31,6 +34,8 @@ from repro.kernels.binning import (bin_fused_matvec_pallas,
                                    bin_scatter_pallas, route_pack_pallas,
                                    route_unpack_pallas)
 from repro.kernels.featurize import featurize_pallas
+from repro.serve import Predictor
+from repro.serve.artifact import LoadedArtifact
 
 M, N, D, B = 64, 500_000, 54, 1 << 21       # Forest Cover fit, m=64
 BN, BT = 128, 512                            # slot-blocked layout geometry
@@ -147,6 +152,35 @@ def test_cross_product_scatter_compiles(compile_tpu):
 def test_cross_product_gather_compiles(compile_tpu):
     text = compile_tpu(bin_gather_pallas, ((M, 1024), I32), ((M, B), F32))
     assert "%wlsh_readout_gather" in text
+
+
+def test_serve_readout_is_a_row_gather(topo, one_chip, no_cache):
+    """The Predictor's jitted featurize -> readout at the Forest serving
+    shape (a padding bucket of 16 queries): the index carries no layout, so
+    the loads are a row gather of the (m, B) table parameter.  No
+    cross-product kernel, and no op but the parameter holds a table-sized
+    buffer (the cross-product path copied it to (m, 1, B))."""
+    lsh = sample_lsh_params(jax.random.PRNGKey(0), M, D, GammaPDF(2.0, 1.0))
+    op = make_operator(lsh, get_bucket_fn("rect"), B,
+                       platform=topo.devices[0].platform)
+    assert (op.backend, op.interpret) == ("pallas", False)
+    model = WLSHKRRModel(lsh=lsh, bucket_name="rect",
+                         beta=jnp.zeros((0,), F32), tables=None,
+                         table_size=B, cg_iters=jnp.asarray(0),
+                         cg_resnorm=jnp.asarray(0.0), backend="pallas")
+    predictor = Predictor()
+    aid = predictor.add_model(LoadedArtifact(
+        artifact_id="forest", model=model, operator=op, norm=None, meta={}))
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((M, B), (16, D))]
+    text = predictor._hosted(aid).predict_fn.lower(*args).compile().as_text()
+    assert "%wlsh_featurize" in text
+    assert "wlsh_readout_gather" not in text
+    big = {s for s in hlo_analysis.tensor_shapes(text)
+           if np.prod(s[1]) >= M * B}
+    assert big == {("f32", (M, B))}
+    table_ops = re.findall(rf"= f32\[{M},{B}\]\S* (\S+)\(", text)
+    assert table_ops and set(table_ops) == {"parameter"}
 
 
 def test_route_pack_compiles(compile_tpu):
