@@ -159,9 +159,10 @@ def main(argv=None) -> dict:
 
     def mxu_matvec(idx, b):
         blay = idx.blocked
-        beta_lay = jnp.concatenate([b, jnp.zeros((1,), b.dtype)])[blay.src]
-        out = fused_mxu(blay.v_block, blay.v_tile, blay.v_phase,
-                        blay.slot_lay, blay.coeff_lay, beta_lay,
+        tail = -n % blay.block_n
+        beta_sorted = jnp.pad(b[blay.order], ((0, 0), (0, tail)))
+        out = fused_mxu(blay.v_block, blay.v_tile_phase, blay.v_off,
+                        blay.slot_lay, blay.coeff_lay, beta_sorted,
                         block_n=blay.block_n, block_t=blay.block_t,
                         interpret=interpret)
         rows = jnp.arange(idx.slot.shape[0])[:, None]

@@ -169,7 +169,7 @@ class WLSHOperator(NamedTuple):
         if self.fused and lay is not None:
             # each backend consumes its own layout group; an index built by
             # the other backend degrades to the split path below
-            if self.backend == "pallas" and lay.src is not None:
+            if self.backend == "pallas" and lay.order is not None:
                 from ..kernels.binning import bin_fused_matvec_op
                 return bin_fused_matvec_op(index, beta, average=average,
                                            interpret=self.interpret)

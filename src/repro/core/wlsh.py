@@ -122,11 +122,20 @@ class BlockedLayout(NamedTuple):
     ``coeff = 0`` so they can never perturb loads or readouts.
 
     Visit v of instance s processes layout block ``v_block[s, v]`` against
-    tile ``v_tile[s, v]``; ``v_phase`` is 0 for the scatter pass and 1 for
-    the gather pass.  Per tile, all scatter visits precede all gather visits,
-    and tiles appear in ascending order, so one VMEM-resident tile serves
-    both passes.  Visits past ``n_visits[s]`` re-gather the last real block
-    (idempotent no-ops that keep the grid static).
+    tile ``v_tile_phase[s, v] // 2``; its parity is 0 for the scatter pass
+    and 1 for the gather pass.  Per tile, all scatter visits precede all
+    gather visits, and tiles appear in ascending order, so one VMEM-resident
+    tile serves both passes.  Visits past ``n_visits[s]`` re-gather the last
+    real block (idempotent no-ops that keep the grid static).
+
+    The fused kernel reads beta in each instance's sorted order
+    (``beta[order[s]]``, n values) rather than along the padded layout (L
+    values): layout block q of tile t is the run of sorted points that
+    starts q·bn after the tile's first, and ``v_off[s, v]`` is that offset
+    for a scatter visit.  Its lanes past the tile's points read the next
+    points' beta, which their coeff 0 cancels.  Gather and padding visits
+    read no beta and repeat the offset of the visit before them, so the
+    kernel's pipeline fetches no beta block for them.
 
     The **split** kernels (distributed psum path — the (m, B) table must
     round-trip through HBM as the scatter→psum→gather barrier) ride the same
@@ -155,13 +164,17 @@ class BlockedLayout(NamedTuple):
     seg_pt: Array        # (m, n) int32 — segment of original point i
     coeff_sorted: Array  # (m, n) float32 — coeff in sorted order
     # pallas (fused kernel) group:
+    order: Array      # (m, n) int32 — stable argsort of slot per instance
     inv_pos: Array    # (m, n) int32 — layout position of original point i
     src: Array        # (m, L) int32 — original point per layout slot (n = pad)
     slot_lay: Array   # (m, L) int32 — CountSketch slot per layout position
     coeff_lay: Array  # (m, L) float32 — weight·sign per position (0 = pad)
     v_block: Array    # (m, V) int32 — visit -> layout block
-    v_tile: Array     # (m, V) int32 — visit -> table tile
-    v_phase: Array    # (m, V) int32 — 0 scatter, 1 gather
+    v_tile_phase: Array  # (m, V) int32 — visit -> 2·table tile + phase
+                         #   (phase 0 scatter, 1 gather)
+    v_off: Array      # (m, V) int32 — visit -> offset into ``order`` of the
+                      #   beta block it reads (scatter: its layout block's
+                      #   first sorted point; else the previous visit's)
     # pallas split-kernel (per-pass) schedules, NB = n//bn + ceil(B/bt):
     vs_block: Array   # (m, NB) int32 — scatter visit -> layout block
     vs_tile: Array    # (m, NB) int32 — scatter visit -> table tile (covers
@@ -277,6 +290,14 @@ def build_blocked_layout(slot: Array, coeff: Array, table_size: int, *,
             v_block = jnp.where(pad, last_b, v_block)
             v_tile = jnp.where(pad, block_tile[last_b], v_tile)
             v_phase = jnp.where(pad, 1, v_phase)
+            # beta offsets in sorted order: block b of tile t starts at
+            # sorted point first_idx[t] + (b - blk_start[t])·bn.  Gather
+            # and padding visits take the offset of their tile's last
+            # block, the one its last scatter visit read
+            off_block = jnp.where(v_phase == 1, blk_start[v_tile + 1] - 1,
+                                  v_block)
+            v_off = first_idx[v_tile].astype(jnp.int32) \
+                + (off_block - blk_start[v_tile]) * bn
 
             # split-kernel per-pass schedules (NB visits each).  Scatter:
             # tile t owns visits [vstart[t], vstart[t+1]) with at least one
@@ -303,19 +324,20 @@ def build_blocked_layout(slot: Array, coeff: Array, table_size: int, *,
             # gather: block j reads its own tile exactly once; padding
             # blocks (slot_lay 0) read tile 0
             vg_tile = jnp.where(vj < total_blocks, block_tile, 0)
-            pal_group = (inv_pos, src, slot_lay, coeff_lay,
-                         v_block, v_tile, v_phase,
+            pal_group = (order, inv_pos, src, slot_lay, coeff_lay,
+                         v_block, 2 * v_tile + v_phase, v_off,
                          vs_block, vs_tile, vg_tile)
         return ref_group, pal_group, 2 * total_blocks
 
     ref_group, pal_group, n_visits = jax.vmap(one)(slot, coeff)
     perm, seg_id, seg_pt, coeff_sorted = ref_group or (None,) * 4
-    (inv_pos, src, slot_lay, coeff_lay, v_block, v_tile, v_phase,
-     vs_block, vs_tile, vg_tile) = pal_group or (None,) * 10
+    (order, inv_pos, src, slot_lay, coeff_lay, v_block, v_tile_phase,
+     v_off, vs_block, vs_tile, vg_tile) = pal_group or (None,) * 11
     return BlockedLayout(perm=perm, seg_id=seg_id, seg_pt=seg_pt,
-                         coeff_sorted=coeff_sorted, inv_pos=inv_pos, src=src,
-                         slot_lay=slot_lay, coeff_lay=coeff_lay,
-                         v_block=v_block, v_tile=v_tile, v_phase=v_phase,
+                         coeff_sorted=coeff_sorted, order=order,
+                         inv_pos=inv_pos, src=src, slot_lay=slot_lay,
+                         coeff_lay=coeff_lay, v_block=v_block,
+                         v_tile_phase=v_tile_phase, v_off=v_off,
                          vs_block=vs_block, vs_tile=vs_tile, vg_tile=vg_tile,
                          n_visits=n_visits.astype(jnp.int32),
                          block_n=bn, block_t=bt, num_tiles=num_tiles)

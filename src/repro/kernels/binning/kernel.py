@@ -32,7 +32,10 @@ Two kernel families:
   the BlockSpec index maps can follow the data-dependent schedule, and the
   table tile lives in a VMEM scratch for both the scatter and the gather
   pass — the (m, B) table never exists in HBM.  O(n/bn + B/bt) visits per
-  instance: genuinely linear when B = Θ(n).
+  instance: genuinely linear when B = Θ(n).  beta enters in each
+  instance's sorted order (m·n values, not the layout's padded m·L): a
+  scatter visit fetches the two aligned sorted blocks its layout block
+  straddles and rotates them into place.
 * **blocked split** (``bin_scatter_blocked_pallas`` /
   ``bin_gather_blocked_pallas``) — the split contract (tables in HBM, so
   the distributed data-axis psum can merge them between the two calls) on
@@ -220,8 +223,32 @@ def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool,
     )(_with_row_axis(slot), _with_row_axis(contrib.astype(jnp.float32)))[:, 0]
 
 
-def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
-                beta_ref, out_ref, table_ref):
+def _sorted_blocks(off, block_n, last):
+    """The aligned blocks of the sorted order that the block_n values from
+    offset ``off`` straddle: off // block_n and the one after it, clamped
+    to the ``last`` block (lanes read from past the end carry coeff 0).
+
+    Schedule arithmetic here and in the kernel bodies uses ``lax.div`` /
+    ``lax.rem`` (offsets are non-negative): jnp's floor division lowers to
+    sign fix-ups that the TPU lowering re-traces every time a program
+    holding the kernel is lowered, which an eager PCG loop does per fit."""
+    lo = jax.lax.div(off, block_n)
+    return lo, jnp.minimum(lo + 1, last)
+
+
+def _sorted_block(lo, hi, shift):
+    """The bn values that start ``shift`` lanes into ``lo``: lanes
+    [shift, bn) of the (k, bn) block ``lo``, then lanes [0, shift) of the
+    block ``hi`` after it."""
+    bn = lo.shape[-1]
+    rot = jax.lax.rem(bn - shift, bn)
+    lane = jax.lax.broadcasted_iota(jnp.int32, lo.shape, 1)
+    return jnp.where(lane < bn - shift, pltpu.roll(lo, rot, 1),
+                     pltpu.roll(hi, rot, 1))
+
+
+def _fused_body(v_block_ref, v_tile_phase_ref, v_off_ref, slot_ref,
+                coeff_ref, beta_lo_ref, beta_hi_ref, out_ref, table_ref):
     """One visit: (point block, table tile, phase) from the prefetched lists.
 
     Tiles arrive in ascending order with all scatter visits before any gather
@@ -230,11 +257,16 @@ def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
     then read by its gather visits — it never round-trips through HBM.
     Padding visits re-gather the last real block against the unchanged tile
     (idempotent full overwrite).  The k RHS columns share every mask.
+
+    A scatter visit's beta is the layout block's run of sorted points, from
+    offset ``v_off`` in the instance's sorted order: the aligned blocks
+    ``beta_lo_ref`` / ``beta_hi_ref`` hold it, ``v_off % bn`` lanes in.
+    Lanes past the tile's points carry another point's beta and coeff 0.
     """
     i, j = pl.program_id(0), pl.program_id(1)
-    tile = v_tile_ref[i, j]
-    phase = v_phase_ref[i, j]
-    prev_tile = v_tile_ref[i, jnp.maximum(j - 1, 0)]
+    tile_phase = v_tile_phase_ref[i, j]
+    tile, phase = jax.lax.div(tile_phase, 2), jax.lax.rem(tile_phase, 2)
+    prev_tile = jax.lax.div(v_tile_phase_ref[i, jnp.maximum(j - 1, 0)], 2)
 
     @pl.when((j == 0) | (tile != prev_tile))
     def _zero():
@@ -244,7 +276,10 @@ def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
 
     @pl.when(phase == 0)
     def _scatter_visit():
-        cols = _scatter(hit, coeff_ref[...] * beta_ref[...])
+        beta = _sorted_block(beta_lo_ref[...], beta_hi_ref[...],
+                             jax.lax.rem(v_off_ref[i, j],
+                                         beta_lo_ref.shape[-1]))
+        cols = _scatter(hit, coeff_ref[...] * beta)
         for c, col in enumerate(cols):
             table_ref[:, c:c + 1] += col
 
@@ -255,43 +290,55 @@ def _fused_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_t", "interpret"))
-def bin_fused_matvec_pallas(v_block, v_tile, v_phase, slot_lay, coeff_lay,
-                            beta_lay, *, block_n: int, block_t: int,
+def bin_fused_matvec_pallas(v_block, v_tile_phase, v_off, slot_lay, coeff_lay,
+                            beta_sorted, *, block_n: int, block_t: int,
                             interpret: bool):
     """Fused scatter→gather over a slot-blocked layout (one kernel call).
 
-    v_block/v_tile/v_phase (m, V) int32 — the per-instance visit schedule
-    (scalar-prefetched; the index maps select layout block ``v_block[i, j]``
-    at visit j).  slot_lay/coeff_lay (m, L) — the blocked layout arrays with
-    L a multiple of ``block_n``.  ``beta_lay`` is (m, L) for one RHS or
-    (m, k, L) for a k-column RHS block laid out along the same permutation.
-    Returns out_lay of ``beta_lay``'s shape, f32, with
+    v_block/v_tile_phase/v_off (m, V) int32 — the per-instance visit
+    schedule (scalar-prefetched; at visit j the index maps select layout
+    block ``v_block[i, j]`` and the sorted beta blocks around offset
+    ``v_off[i, j]``; ``v_tile_phase`` is 2·tile + phase).  slot_lay/coeff_lay
+    (m, L) — the blocked layout arrays with L a multiple of ``block_n``.
+    ``beta_sorted`` is (m, N) for one RHS or (m, k, N) for a k-column RHS
+    block: beta in each instance's sorted order, N a multiple of
+    ``block_n``.  Returns out_lay (m, L) — or (m, k, L) — f32, with
     ``out_lay[..., p] = coeff_lay[p] * table[slot_lay[p]]`` at every real
-    layout position (padding positions have coeff 0).  The (m, B[, k]) table
-    exists only as a (block_t, 1|k) VMEM scratch tile — the k columns ride
-    the same masks, so the extra HBM traffic over single-RHS is just
+    layout position (padding positions have coeff 0).  The (m, B[, k])
+    table exists only as a (block_t, 1|k) VMEM scratch tile — the k columns
+    ride the same masks, so the extra HBM traffic over single-RHS is just
     beta/out themselves.
     """
     m, layout_len = slot_lay.shape
-    if layout_len % block_n:
-        raise ValueError("layout length must be a multiple of block_n")
-    beta3 = _with_row_axis(beta_lay.astype(jnp.float32))
-    k = beta3.shape[1]
+    beta3 = _with_row_axis(beta_sorted.astype(jnp.float32))
+    k, sorted_len = beta3.shape[1:]
+    if layout_len % block_n or sorted_len % block_n:
+        raise ValueError("layout and sorted lengths must be multiples of "
+                         "block_n")
+    last = sorted_len // block_n - 1
 
     def specs(s):
-        def index(i, j, vb, vt, vp):
+        def block(i, j, vb, vtp, vo):
             return i + s, 0, vb[i, j]
-        lay_spec = pl.BlockSpec((None, 1, block_n), index)
-        beta_spec = pl.BlockSpec((None, k, block_n), index)
-        return [lay_spec, lay_spec, beta_spec], beta_spec
+
+        def beta_lo(i, j, vb, vtp, vo):
+            return i + s, 0, _sorted_blocks(vo[i, j], block_n, last)[0]
+
+        def beta_hi(i, j, vb, vtp, vo):
+            return i + s, 0, _sorted_blocks(vo[i, j], block_n, last)[1]
+        lay_spec = pl.BlockSpec((None, 1, block_n), block)
+        return ([lay_spec, lay_spec,
+                 pl.BlockSpec((None, k, block_n), beta_lo),
+                 pl.BlockSpec((None, k, block_n), beta_hi)],
+                pl.BlockSpec((None, k, block_n), block))
 
     out = _grouped_call(
-        _fused_body, (v_block, v_tile, v_phase),
-        (_with_row_axis(slot_lay), _with_row_axis(coeff_lay), beta3), specs,
-        jax.ShapeDtypeStruct(beta3.shape, jnp.float32),
+        _fused_body, (v_block, v_tile_phase, v_off),
+        (_with_row_axis(slot_lay), _with_row_axis(coeff_lay), beta3, beta3),
+        specs, jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
         name="wlsh_fused_matvec", interpret=interpret,
         scratch_shapes=[pltpu.VMEM((block_t, k), jnp.float32)])
-    return out[:, 0] if beta_lay.ndim == 2 else out
+    return out[:, 0] if beta_sorted.ndim == 2 else out
 
 
 def _scatter_blocked_body(vs_block_ref, vs_tile_ref, slot_ref, contrib_ref,

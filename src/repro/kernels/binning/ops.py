@@ -190,32 +190,36 @@ def bin_fused_matvec_op(index: TableIndex, beta, *, interpret: bool,
     """Fused one-pass WLSH table matvec off the slot-blocked layout.
 
     Requires ``index.blocked`` (see ``core.wlsh.build_blocked_layout``).  The
-    per-iteration jnp work is one gather (``beta`` into the sorted layout)
-    and one gather back (``inv_pos``) — everything between runs inside a
-    single Pallas kernel whose table tile never leaves VMEM.
+    per-iteration jnp work is one m·n gather (``beta`` into each instance's
+    sorted order, ``order``) and one m·n gather back (``inv_pos``) —
+    everything between runs inside a single Pallas kernel, which reads each
+    layout block's beta from the sorted order and whose table tile never
+    leaves VMEM.
 
-    ``beta`` is (n,) or (n, k): a RHS block is laid out as (m, k, L) along
-    the same slot permutation and the k columns share every one-hot tile
-    product inside the kernel (see ``bin_fused_matvec_pallas``).
+    ``beta`` is (n,) or (n, k): a RHS block is gathered as (m, k, n) and the
+    k columns share every one-hot tile product inside the kernel (see
+    ``bin_fused_matvec_pallas``).
     """
     lay = index.blocked
-    if lay is None or lay.src is None:
+    if lay is None or lay.order is None:
         raise ValueError("fused matvec needs a slot-blocked index with the "
                          "pallas group; build it with build_blocked_layout"
                          "(parts='pallas'|'both') / a pallas-backend "
                          "build_index(blocked=True)")
-    m = index.slot.shape[0]
+    m, n = index.slot.shape
     multi = beta.ndim == 2
     with jax.named_scope(LAYOUT_SCOPE):
-        pad = jnp.zeros((1,) + beta.shape[1:], jnp.float32)
-        beta_pad = jnp.concatenate([jnp.asarray(beta, jnp.float32), pad])
-        beta_lay = beta_pad[lay.src]           # (m, L) | (m, L, k)
+        beta_sorted = jnp.asarray(beta, jnp.float32)[lay.order]
         if multi:
-            beta_lay = jnp.swapaxes(beta_lay, 1, 2)          # (m, k, L)
+            beta_sorted = jnp.swapaxes(beta_sorted, 1, 2)    # (m, k, n)
+        tail = _round_up(n, lay.block_n) - n
+        if tail:
+            beta_sorted = jnp.pad(beta_sorted, ((0, 0),) * beta.ndim
+                                  + ((0, tail),))
     with jax.named_scope(KERNEL_SCOPE):
         out_lay = bin_fused_matvec_pallas(
-            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
-            lay.coeff_lay, beta_lay, block_n=lay.block_n,
+            lay.v_block, lay.v_tile_phase, lay.v_off, lay.slot_lay,
+            lay.coeff_lay, beta_sorted, block_n=lay.block_n,
             block_t=lay.block_t, interpret=interpret)
     with jax.named_scope(LAYOUT_SCOPE):
         rows = jnp.arange(m, dtype=jnp.int32)[:, None]

@@ -340,15 +340,19 @@ def test_visit_kernels_split_over_smem_budget(monkeypatch, kernel):
     m, length = lay.slot_lay.shape
     beta = jax.random.normal(key, (m, length))
     beta4 = jax.random.normal(key, (m, 4, length))
+    # beta in sorted order for the fused kernel: n = 200 -> 2 whole blocks
+    sorted_len = -(-lay.order.shape[1] // lay.block_n) * lay.block_n
+    beta_s = beta[:, :sorted_len]
+    beta4_s = beta4[:, :, :sorted_len]
     calls = {
         "fused": (lambda: bin_fused_matvec_pallas(
-            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
-            lay.coeff_lay, beta, block_n=lay.block_n, block_t=lay.block_t,
+            lay.v_block, lay.v_tile_phase, lay.v_off, lay.slot_lay,
+            lay.coeff_lay, beta_s, block_n=lay.block_n, block_t=lay.block_t,
             interpret=True), 3 * lay.v_block.shape[1]),
         "fused_k4": (lambda: bin_fused_matvec_pallas(
-            lay.v_block, lay.v_tile, lay.v_phase, lay.slot_lay,
-            lay.coeff_lay, beta4, block_n=lay.block_n, block_t=lay.block_t,
-            interpret=True), 3 * lay.v_block.shape[1]),
+            lay.v_block, lay.v_tile_phase, lay.v_off, lay.slot_lay,
+            lay.coeff_lay, beta4_s, block_n=lay.block_n,
+            block_t=lay.block_t, interpret=True), 3 * lay.v_block.shape[1]),
         "scatter": (lambda: bin_scatter_blocked_pallas(
             lay.vs_block, lay.vs_tile, lay.slot_lay, beta,
             num_tiles=lay.num_tiles, block_n=lay.block_n,

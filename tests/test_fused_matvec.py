@@ -11,13 +11,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import (GammaPDF, WLSHKernelSpec, cg_solve, get_bucket_fn,
                         make_operator, sample_lsh_params, wlsh_krr_fit)
-from repro.core.wlsh import (build_blocked_layout, build_table_index,
-                             table_matvec, table_matvec_fused)
+from repro.core.wlsh import (TableIndex, build_blocked_layout,
+                             build_table_index, table_loads, table_matvec,
+                             table_matvec_fused, table_readout)
 from repro.hlo_analysis import materializes_shape
 from repro.kernels.binning import bin_fused_matvec_op
+from repro.kernels.binning import kernel as kmod
 
 
 def _setup(key, n, d, m, table_size, bucket="rect"):
@@ -99,6 +103,133 @@ def test_fused_matvec_all_zero_weights():
                                                     table_size))
     assert bool(jnp.all(table_matvec_fused(idx, beta) == 0.0))
     assert bool(jnp.all(bin_fused_matvec_op(idx, beta, interpret=True) == 0.0))
+
+
+def _padded_body(v_block_ref, v_tile_ref, v_phase_ref, slot_ref, coeff_ref,
+                 beta_ref, out_ref, table_ref):
+    """The fused kernel's visit as it was when beta arrived along the padded
+    layout: every visit reads layout block ``v_block`` of ``beta_ref``."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    tile = v_tile_ref[i, j]
+    phase = v_phase_ref[i, j]
+    prev_tile = v_tile_ref[i, jnp.maximum(j - 1, 0)]
+
+    @pl.when((j == 0) | (tile != prev_tile))
+    def _zero():
+        table_ref[...] = jnp.zeros_like(table_ref)
+
+    hit = kmod._hit(slot_ref, tile, table_ref.shape[0])
+
+    @pl.when(phase == 0)
+    def _scatter_visit():
+        cols = kmod._scatter(hit, coeff_ref[...] * beta_ref[...])
+        for c, col in enumerate(cols):
+            table_ref[:, c:c + 1] += col
+
+    @pl.when(phase == 1)
+    def _gather_visit():
+        cols = [table_ref[:, c:c + 1] for c in range(table_ref.shape[1])]
+        out_ref[...] = coeff_ref[...] * kmod._gather(hit, cols)
+
+
+def _padded_matvec(index, beta, average):
+    """The fused matvec off the padded layout: beta_pad[lay.src] (m·L
+    values, padding reads an appended zero) into the kernel above, then
+    the same gather back through ``inv_pos``."""
+    lay = index.blocked
+    pad = jnp.zeros((1,) + beta.shape[1:], jnp.float32)
+    beta_lay = jnp.concatenate([beta, pad])[lay.src]
+    if beta.ndim == 2:
+        beta_lay = jnp.swapaxes(beta_lay, 1, 2)              # (m, k, L)
+    beta3 = kmod._with_row_axis(beta_lay)
+    k, bn = beta3.shape[1], lay.block_n
+
+    def specs(s):
+        def index_map(i, j, vb, vt, vp):
+            return i + s, 0, vb[i, j]
+        lay_spec = pl.BlockSpec((None, 1, bn), index_map)
+        beta_spec = pl.BlockSpec((None, k, bn), index_map)
+        return [lay_spec, lay_spec, beta_spec], beta_spec
+
+    out = kmod._grouped_call(
+        _padded_body,
+        (lay.v_block, lay.v_tile_phase // 2, lay.v_tile_phase % 2),
+        (kmod._with_row_axis(lay.slot_lay),
+         kmod._with_row_axis(lay.coeff_lay), beta3), specs,
+        jax.ShapeDtypeStruct(beta3.shape, jnp.float32),
+        name="padded_fused_matvec", interpret=True,
+        scratch_shapes=[pltpu.VMEM((lay.block_t, k), jnp.float32)])
+    rows = jnp.arange(lay.v_block.shape[0])[:, None]
+    vals = (jnp.swapaxes(out, 1, 2) if beta.ndim == 2 else out[:, 0])[
+        rows, lay.inv_pos]
+    return jnp.mean(vals, axis=0) if average else jnp.sum(vals, axis=0)
+
+
+def _index_by_tile(counts, table_size, block_t, seed):
+    """A table index whose instance s puts counts[s][t] points, at random
+    slots, in table tile t: tiles with more than block_n points, empty
+    tiles and a tile starting near the end of the sorted order on demand."""
+    rng = np.random.default_rng(seed)
+    slot = np.stack([
+        rng.permutation(np.concatenate([
+            rng.integers(t * block_t, (t + 1) * block_t, c)
+            for t, c in enumerate(row)]))
+        for row in counts]).astype(np.int32)
+    coeff = rng.normal(size=slot.shape).astype(np.float32)
+    idx = TableIndex(slot=jnp.asarray(slot), sign=jnp.asarray(np.sign(coeff)),
+                     weight=jnp.asarray(np.abs(coeff)),
+                     coeff=jnp.asarray(coeff), table_size=table_size)
+    return idx._replace(blocked=build_blocked_layout(
+        idx.slot, idx.coeff, table_size, block_t=block_t, parts="pallas"))
+
+
+def _hashed_index(n, d, m, table_size):
+    *_, fidx_pal = _setup(jax.random.PRNGKey(n + d + m), n, d, m, table_size)
+    return fidx_pal
+
+
+_SORTED_READ_CASES = {
+    # tiles of 300-550 points (3-5 blocks), tiles 1-3 of 4 empty in turn,
+    # n = 700 not a multiple of bn: the last block's +1 is clamped
+    "multi_block_empty_tiles": lambda: _index_by_tile(
+        [[400, 0, 300, 0], [0, 150, 0, 550], [700, 0, 0, 0]], 2048, 512, 0),
+    # n = 256 = 2 blocks; tile 1 starts 200 points in: block 1's +1 clamps
+    "clamp_at_aligned_end": lambda: _index_by_tile([[200, 56]], 1024, 512,
+                                                   1),
+    "hashed": lambda: _hashed_index(300, 5, 4, 1024),
+}
+
+
+@pytest.mark.parametrize("average", [True, False])
+@pytest.mark.parametrize("k", [None, 1, 4])
+@pytest.mark.parametrize("case", sorted(_SORTED_READ_CASES))
+def test_fused_matvec_sorted_read_matches_padded_layout(case, k, average):
+    """The fused kernel reads beta from each instance's sorted order (an
+    m·n gather) instead of the padded layout (m·L): the same values reach
+    the same lanes in the same order, so the matvec is bitwise the padded
+    layout's.  Every case has scatter visits at an unaligned offset (the
+    roll), and one whose next sorted block lies past the end (the clamp)."""
+    index = _SORTED_READ_CASES[case]()
+    lay = index.blocked
+    n, bn = index.slot.shape[1], lay.block_n
+    last = -(-n // bn) - 1
+    scatter = lay.v_tile_phase % 2 == 0
+    assert bool(jnp.any(scatter & (lay.v_off % bn != 0)))
+    if case != "hashed":
+        assert bool(jnp.any(scatter & (lay.v_off // bn + 1 > last)))
+    # the kernel's two sorted blocks lie inside the array (the interpreter
+    # would clamp an out-of-range block where the chip reads past the end)
+    lo, hi = kmod._sorted_blocks(lay.v_off, bn, last)
+    assert bool(jnp.all((0 <= lo) & (lo <= hi) & (hi <= last)))
+    shape = (n,) if k is None else (n, k)
+    beta = jax.random.normal(jax.random.PRNGKey(k or 0), shape)
+    got = bin_fused_matvec_op(index, beta, interpret=True, average=average)
+    want = _padded_matvec(index, beta, average)
+    assert got.shape == want.shape == shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and both agree with the split reference matvec
+    ref = table_readout(index, table_loads(index, beta), average=average)
+    np.testing.assert_allclose(got, ref, atol=2e-5)
 
 
 def test_blocked_layout_schedules_O_n_tiles():

@@ -27,7 +27,9 @@ from repro.core import get_bucket_fn, make_operator, sample_lsh_params
 from repro.core.krr import WLSHKRRModel
 from repro.core.lsh import GammaPDF
 from repro.core.operator import default_table_size
-from repro.kernels.binning import (bin_fused_matvec_pallas,
+from repro.core.wlsh import BlockedLayout, TableIndex
+from repro.kernels.binning import (bin_fused_matvec_op,
+                                   bin_fused_matvec_pallas,
                                    bin_gather_blocked_pallas,
                                    bin_gather_pallas,
                                    bin_scatter_blocked_pallas,
@@ -41,6 +43,7 @@ M, N, D, B = 64, 500_000, 54, 1 << 21       # Forest Cover fit, m=64
 BN, BT = 128, 512                            # slot-blocked layout geometry
 TILES = B // BT
 NB = N // BN + TILES                         # layout blocks per instance
+NS = -(-N // BN) * BN                        # sorted order, whole blocks
 N_LOC = N // 4                               # hash-join: 4 data shards
 NB_LOC = N_LOC // BN + TILES
 CELL_TILES = M * N_LOC // BT                 # wire cells / tile width
@@ -103,7 +106,7 @@ def test_featurize_compiles(compile_tpu, bucket):
 @pytest.mark.parametrize("k", [None, 4])
 def test_fused_matvec_compiles(compile_tpu, k):
     lay = (M, NB * BN)
-    beta = lay if k is None else (M, k, NB * BN)
+    beta = (M, NS) if k is None else (M, k, NS)
     text = compile_tpu(bin_fused_matvec_pallas, ((M, 2 * NB), I32),
                        ((M, 2 * NB), I32), ((M, 2 * NB), I32), (lay, I32),
                        (lay, F32), (beta, F32), block_n=BN, block_t=BT)
@@ -112,19 +115,52 @@ def test_fused_matvec_compiles(compile_tpu, k):
 
 @pytest.mark.parametrize("n, fits", [(1 << 20, True), ((1 << 20) + 1, False)])
 def test_fused_matvec_smem_boundary(compile_tpu, n, fits):
-    """One instance's fused schedule (three lists of 2·NB int32 visits,
-    NB = n/bn + B/bt) fits the SMEM budget of one call up to n = 2^20 at
-    B = default_table_size(n); one point more doubles B and is refused."""
+    """One instance's fused schedule (three lists of 2·NB int32 visits:
+    layout block, tile and phase packed as 2·tile + phase, and the sorted
+    beta offset; NB = n/bn + B/bt) fits the SMEM budget of one call up to
+    n = 2^20 at B = default_table_size(n); one point more doubles B and is
+    refused."""
     nb = n // BN + default_table_size(n) // BT
     shapes = 3 * [((1, 2 * nb), I32)] + [((1, nb * BN), I32),
                                          ((1, nb * BN), F32),
-                                         ((1, nb * BN), F32)]
+                                         ((1, -(-n // BN) * BN), F32)]
     if fits:
         compile_tpu(bin_fused_matvec_pallas, *shapes, block_n=BN, block_t=BT)
     else:
         with pytest.raises(ValueError, match="SMEM"):
             compile_tpu(bin_fused_matvec_pallas, *shapes, block_n=BN,
                         block_t=BT)
+
+
+@pytest.mark.parametrize("k", [None, 4])
+def test_fused_matvec_gathers_sorted_order(compile_tpu, k):
+    """The fused matvec op at the Forest shape reads beta in each
+    instance's sorted order: no gather in the compiled program yields more
+    than m·n values per RHS column.  Gathering beta along the padded layout
+    yielded m·L = m·(n/bn + B/bt)·bn, here over twice as many."""
+    def matvec(slot, order, inv_pos, slot_lay, coeff_lay, v_block,
+               v_tile_phase, v_off, beta, *, interpret):
+        lay = BlockedLayout(**{
+            **dict.fromkeys(BlockedLayout._fields),
+            **dict(order=order, inv_pos=inv_pos, slot_lay=slot_lay,
+                   coeff_lay=coeff_lay, v_block=v_block,
+                   v_tile_phase=v_tile_phase, v_off=v_off, block_n=BN,
+                   block_t=BT, num_tiles=TILES)})
+        index = TableIndex(slot=slot, sign=None, weight=None, coeff=None,
+                           table_size=B, blocked=lay)
+        return bin_fused_matvec_op(index, beta, interpret=interpret)
+
+    points, lay, visits = (M, N), (M, NB * BN), (M, 2 * NB)
+    beta = (N,) if k is None else (N, k)
+    text = compile_tpu(matvec, (points, I32), (points, I32), (points, I32),
+                       (lay, I32), (lay, F32), (visits, I32), (visits, I32),
+                       (visits, I32), (beta, F32))
+    assert "%wlsh_fused_matvec" in text
+    gathered = [int(np.prod([int(d) for d in dims.split(",")]))
+                for dims in re.findall(r"= f32\[([\d,]+)\]\S* gather\(",
+                                       text)]
+    assert gathered and max(gathered) <= M * N * (k or 1)
+    assert M * NB * BN > 2 * M * N
 
 
 def test_blocked_scatter_compiles(compile_tpu):
