@@ -33,7 +33,8 @@ from repro.core import (GammaPDF, get_bucket_fn, make_operator,
                         table_diag)
 from repro.core.precond import DEFAULT_NYSTROM_RANK
 from repro.core.operator import default_table_size
-from repro.core.wlsh import build_exact_index, exact_kernel_matrix, exact_matvec
+from repro.core.wlsh import (build_exact_index, exact_kernel_matrix,
+                             exact_matvec, table_matvec)
 
 from .common import emit, refuse_on_tpu, time_fn
 
@@ -118,22 +119,19 @@ def run(ns=(1024, 4096, 16384), d: int = 8, m: int = 16, seed: int = 0, *,
         beta = jax.random.normal(jax.random.fold_in(key, 2), (n,))
         table_size = default_table_size(n, min_pow=10)
 
-        op_ref = make_operator(lsh, f, table_size, backend="reference",
-                               fused=False)
-        op_fused = make_operator(lsh, f, table_size, backend="reference",
-                                 fused=True)
+        op_ref = make_operator(lsh, f, table_size, backend="reference")
         feats = op_ref.featurize(x)
-        tidx = op_ref.build_index(feats)            # split (no layout)
-        fidx = op_fused.build_index(feats)          # slot-blocked
+        tidx = op_ref.build_index(feats, blocked=False)   # split (no layout)
+        fidx = op_ref.build_index(feats)                  # slot-blocked
         eidx = build_exact_index(feats)
 
         row = {"n": n, "m": m, "d": d, "table_size": table_size,
                "exact_us": time_fn(jax.jit(
                    lambda b: exact_matvec(eidx, b)), beta, **time_args) * 1e6,
                "reference_us": time_fn(jax.jit(
-                   lambda b: op_ref.matvec(tidx, b)), beta, **time_args) * 1e6,
+                   lambda b: table_matvec(tidx, b)), beta, **time_args) * 1e6,
                "fused_us": time_fn(jax.jit(
-                   lambda b: op_fused.matvec(fidx, b)), beta,
+                   lambda b: op_ref.matvec(fidx, b)), beta,
                    **time_args) * 1e6}
         row["fused_speedup"] = row["reference_us"] / row["fused_us"]
         row["fused_parity_regime"] = (not on_tpu) and n >= FUSED_PARITY_MIN_N
@@ -152,48 +150,27 @@ def run(ns=(1024, 4096, 16384), d: int = 8, m: int = 16, seed: int = 0, *,
             row["dense_us"] = None
             row["dense_proxy"] = None
 
-        if not with_pallas:
-            row["pallas_us"] = None
-            row["pallas_fused_us"] = None
-            row["pallas_fused_speedup"] = None
-            row["pallas_split_blocked_us"] = None
-            row["pallas_split_blocked_speedup"] = None
-            row["pallas_interpret"] = None
-            row["pallas_skipped"] = "disabled"
-        elif on_tpu or n <= 1024:
+        if with_pallas and (on_tpu or n <= 1024):
             # off-TPU the Pallas kernels run in interpret mode (the kernel
             # body executes in Python) — correctness validation only,
             # meaningless as a wall-clock datapoint, so keep n tiny
-            op_pal = make_operator(lsh, f, table_size, backend="pallas",
-                                   fused=False)
-            op_pal_fused = make_operator(lsh, f, table_size, backend="pallas",
-                                         fused=True)
-            fidx_pal = op_pal_fused.build_index(feats)  # pallas layout group
-            # split contract (tables in HBM, psum-able) on the visit-list
-            # schedule: a blocked index through the fused=False operator
-            bidx_pal = op_pal.build_index(feats, blocked=True)
-            row["pallas_us"] = time_fn(jax.jit(
-                lambda b: op_pal.matvec(tidx, b)), beta, **time_args) * 1e6
+            op_pal = make_operator(lsh, f, table_size, backend="pallas")
+            fidx_pal = op_pal.build_index(feats)    # pallas layout group
             row["pallas_fused_us"] = time_fn(jax.jit(
-                lambda b: op_pal_fused.matvec(fidx_pal, b)), beta,
+                lambda b: op_pal.matvec(fidx_pal, b)), beta,
                 **time_args) * 1e6
-            row["pallas_fused_speedup"] = \
-                row["pallas_us"] / row["pallas_fused_us"]
+            # split contract (tables in HBM, psum-able) on the visit-list
+            # schedule: the same index through loads then readout
             row["pallas_split_blocked_us"] = time_fn(jax.jit(
-                lambda b: op_pal.matvec(bidx_pal, b)), beta,
-                **time_args) * 1e6
-            row["pallas_split_blocked_speedup"] = \
-                row["pallas_us"] / row["pallas_split_blocked_us"]
+                lambda b: op_pal.readout(fidx_pal, op_pal.loads(fidx_pal, b))),
+                beta, **time_args) * 1e6
             row["pallas_interpret"] = op_pal.interpret
             row["pallas_skipped"] = None
         else:
-            row["pallas_us"] = None
             row["pallas_fused_us"] = None
-            row["pallas_fused_speedup"] = None
             row["pallas_split_blocked_us"] = None
-            row["pallas_split_blocked_speedup"] = None
             row["pallas_interpret"] = None
-            row["pallas_skipped"] = "interpret"
+            row["pallas_skipped"] = "interpret" if with_pallas else "disabled"
 
         if not with_pcg:
             for k in PCG_KEYS:
@@ -244,7 +221,7 @@ for n in ns:
     table_size = default_table_size(n, min_pow=10)
     cfg = KRRStepConfig(m=m, table_size=table_size, lam=0.5, cg_iters=iters,
                         data_axes=("pod", "data"), model_axis="model",
-                        backend="reference", fused=False)
+                        backend="reference")
 
     yk = jax.random.normal(jax.random.fold_in(key, 3), (n, 8))
 
@@ -341,19 +318,13 @@ def calibration_us(iters: int = 10) -> float:
 
 def main(json_path: str | None = None, with_dist: bool = True) -> None:
     rows = run()
-    print("n,exact_us,reference_us,fused_us,pallas_us,pallas_fused_us,dense_us")
+    print("n,exact_us,reference_us,fused_us,pallas_fused_us,"
+          "pallas_split_blocked_us,dense_us")
     for r in rows:
-        pal = ("skip" if r["pallas_us"] is None else f"{r['pallas_us']:.1f}")
-        palf = ("skip" if r["pallas_fused_us"] is None
-                else f"{r['pallas_fused_us']:.1f}")
+        pal = ["skip" if r[k] is None else f"{r[k]:.1f}"
+               for k in ("pallas_fused_us", "pallas_split_blocked_us")]
         print(f"{r['n']},{r['exact_us']:.1f},{r['reference_us']:.1f},"
-              f"{r['fused_us']:.1f},{pal},{palf},{r['dense_us']:.1f}")
-    for r in rows:
-        if r["pallas_split_blocked_us"] is not None:
-            print(f"[blocked-split] n={r['n']}: cross-product "
-                  f"{r['pallas_us']:.0f}us -> visit-list "
-                  f"{r['pallas_split_blocked_us']:.0f}us "
-                  f"({r['pallas_split_blocked_speedup']:.1f}x, interpret)")
+              f"{r['fused_us']:.1f},{pal[0]},{pal[1]},{r['dense_us']:.1f}")
     for r in rows:
         if r["pcg_iters"] is not None:
             print(f"[pcg] n={r['n']}: cg {r['cg_iters']} iters "

@@ -17,10 +17,10 @@ B = default_table_size(n), rect bucket:
   kernel with its one-hot products as MXU matmuls at HIGHEST precision
   (the formulation the select-and-reduce replaced), the reference sorted
   segment-sum, and the blocked split scatter + gather;
-- the readout of 1024 query points from (m, B) tables: the cross-product
-  gather kernel, its MXU variant, XLA's row gather ``tables[s, slot]``, and
-  the serving readout (``bin_readout_op`` on the layout-less query index:
-  that row gather, the coefficients and the instance mean).
+- the readout of 1024 query points from (m, B) tables: XLA's row gather
+  ``tables[s, slot]``, and the serving readout (``bin_readout_op`` on the
+  layout-less query index: that row gather, the coefficients and the
+  instance mean).
 
 Variants are checked against the kernel they stand in for and the largest
 difference is printed.  Times from a CPU run say nothing about a device.
@@ -81,8 +81,6 @@ def _with_globals(fn, **names):
 _MXU = dict(_scatter=_scatter_mxu, _gather=_gather_mxu)
 fused_mxu = _with_globals(bk.bin_fused_matvec_pallas,
                           _fused_body=_with_globals(bk._fused_body, **_MXU))
-gather_mxu = _with_globals(bk.bin_gather_pallas,
-                           _gather_body=_with_globals(bk._gather_body, **_MXU))
 
 
 def _on_index(fn, index):
@@ -183,18 +181,10 @@ def main(argv=None) -> dict:
 
     tables = _on_index(pal.loads, index)(beta)
     q = pal.featurize_buckets(xte[:QUERIES])
-    vals = probe("cross gather 1024 q",
-                 jax.jit(lambda s, t: bk.bin_gather_pallas(
-                     s, t, interpret=interpret)), q.slot, tables)
-    differ("cross gather MXU vs cross gather",
-           probe("cross gather MXU HIGHEST 1024 q",
-                 jax.jit(lambda s, t: gather_mxu(s, t, interpret=interpret)),
-                 q.slot, tables), vals)
-    differ("xla row gather vs cross gather",
-           probe("xla row gather 1024 q",
+    vals = probe("xla row gather 1024 q",
                  jax.jit(lambda s, t: jnp.take_along_axis(t, s, axis=1)),
-                 q.slot, tables), vals)
-    differ("serving readout (row gather) vs cross gather",
+                 q.slot, tables)
+    differ("serving readout vs xla row gather",
            probe("serving readout (row gather) 1024 q",
                  jax.jit(lambda t: bin_readout_op(q, t, interpret=interpret)),
                  tables),

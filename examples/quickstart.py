@@ -37,10 +37,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default="auto",
                     choices=["auto", "reference", "pallas"])
-    ap.add_argument("--fused", default=True,
-                    action=argparse.BooleanOptionalAction,
-                    help="one-pass slot-blocked CG matvec (--no-fused keeps "
-                         "the split scatter->gather path reachable for A/B)")
     ap.add_argument("--precond", default="none",
                     choices=["none", "jacobi", "nystrom"],
                     help="PCG preconditioner for the WLSH solve")
@@ -96,7 +92,7 @@ def main():
     t0 = time.time()
     model = wlsh_krr_fit(jax.random.fold_in(key, 1), xtr, target, spec,
                          m=400, lam=lam, backend=args.backend,
-                         fused=args.fused, precond=args.precond,
+                         precond=args.precond,
                          precond_rank=args.precond_rank,
                          solve_checkpoint_dir=args.solve_checkpoint_dir,
                          solve_checkpoint_every=args.solve_checkpoint_every)

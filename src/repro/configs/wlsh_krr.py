@@ -22,11 +22,6 @@ class WLSHKRRConfig:
     backend: str = "auto"         # WLSH operator backend (core/operator.py):
                                   # auto = Pallas kernels on TPU,
                                   # jnp reference elsewhere
-    fused: bool = True            # one-pass slot-blocked matvec where legal
-                                  # (unsharded data axes); split otherwise
-    blocked_split: bool = True    # sharded psum path: visit-list split
-                                  # kernels off the same slot-blocked layout
-                                  # (pallas backend; tables stay psum-able)
     precond: str = "none"         # PCG preconditioner (core/precond.py):
                                   # none | jacobi (any mesh) | nystrom
                                   # (unsharded data axes only)

@@ -57,10 +57,6 @@ class KRRStepConfig(NamedTuple):
     data_axes: tuple[str, ...] = ("data",)
     model_axis: str = "model"
     backend: str = "auto"  # operator backend inside each shard
-    fused: bool = True     # one-pass local matvec when the data axes are size 1
-    blocked_split: bool = True  # visit-list split kernels for the sharded
-                                # psum path (pallas backend; the (m, B)
-                                # tables stay in HBM so the psum is unchanged)
     precond: str = "none"  # 'none' | 'jacobi' (any mesh) | 'nystrom'
                            # (unsharded data axes only — see make_krr_step)
     precond_rank: int = DEFAULT_NYSTROM_RANK
@@ -71,18 +67,15 @@ class KRRStepConfig(NamedTuple):
 
 
 def _shard_operator(cfg: KRRStepConfig, f: BucketFn, lsh_local: LSHParams,
-                    mesh: Mesh, *, fused: bool | None = None) -> WLSHOperator:
+                    mesh: Mesh) -> WLSHOperator:
     """Per-shard operator over the local LSH slice.  Backend and interpret
     mode follow the platform of the mesh's devices (shard_map bodies must
     see a concrete choice): a step compiled for TPU devices gets compiled
-    kernels whatever the host's default backend.  ``fused`` overrides
-    cfg.fused: a data-sharded step passes False so its matvec takes the
-    split (psum-able) kernels."""
+    kernels whatever the host's default backend."""
     platform = platform_of(mesh)
     return WLSHOperator(lsh=lsh_local, bucket=f, table_size=cfg.table_size,
                         backend=resolve_backend(cfg.backend, platform),
-                        interpret=resolve_interpret(None, platform),
-                        fused=cfg.fused if fused is None else fused)
+                        interpret=resolve_interpret(None, platform))
 
 
 def _data_shard_count(mesh: Mesh, cfg: KRRStepConfig) -> int:
@@ -106,23 +99,22 @@ def make_distributed_matvec(cfg: KRRStepConfig, op: WLSHOperator, *,
     (``_data_shard_count``) — required so a forgotten kwarg cannot silently
     disable the fused path.
 
-    The split loads → psum → readout sandwich is required whenever the data
-    axes are sharded: the table psum is the scatter→gather barrier, so the
-    (m_loc, B) tables must exist between the two.  With a single data shard
-    (model-parallel-only meshes) there is nothing to merge, and the fused
-    one-pass matvec (slot-blocked index) runs locally with only the final
-    model-axis psum.
+    One rule picks the path: the fused one-pass matvec when the index has
+    a slot-blocked layout and the data axes are unsharded, else the split
+    loads → psum → readout sandwich.  With sharded data axes the table psum
+    is the scatter→gather barrier, so the (m_loc, B) tables must exist
+    between the two; with a single data shard (model-parallel-only meshes)
+    there is nothing to merge, and the fused matvec runs locally with only
+    the final model-axis psum.
 
-    The split sandwich itself is still visit-list scheduled when the index
-    carries the slot-blocked layout (``cfg.blocked_split``, pallas backend):
-    ``op.loads``/``op.readout`` dispatch to the blocked split kernels, which
-    walk only the O(n/bn + B/bt) real collisions per pass while landing the
-    same psum-able (m_loc, B[, k]) tables in HBM.
+    The split sandwich is itself visit-list scheduled when the index carries
+    the pallas layout group: ``op.loads``/``op.readout`` dispatch to the
+    blocked split kernels, which walk only the O(n/bn + B/bt) real
+    collisions per pass while landing the same psum-able (m_loc, B[, k])
+    tables in HBM.
     """
-    local_fused = cfg.fused and n_data_shards == 1
-
     def matvec(index, beta_local):
-        if local_fused and getattr(index, "blocked", None) is not None:
+        if n_data_shards == 1 and getattr(index, "blocked", None) is not None:
             out = op.matvec(index, beta_local, average=False)
         else:
             tables = jax.lax.psum(op.loads(index, beta_local), cfg.data_axes)
@@ -264,13 +256,11 @@ def make_krr_step(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
                           r1=P(cfg.model_axis, None), r2=P(cfg.model_axis, None)))
     out_specs = (data_spec, P(), P(cfg.model_axis, None))
     n_data = _data_shard_count(mesh, cfg)
-    local_fused = cfg.fused and n_data == 1
-    # sharded data axes keep the split (psum-able) sandwich, but the pallas
-    # scatter/gather still follow the slot-blocked visit lists when the
-    # index carries the layout — only the reference split path ignores it
-    want_blocked = local_fused or (
-        cfg.blocked_split
-        and resolve_backend(cfg.backend, platform_of(mesh)) == "pallas")
+    # one data shard runs the fused matvec off the layout; sharded data axes
+    # keep the split (psum-able) sandwich, whose pallas scatter/gather still
+    # follow the layout's visit lists — only the reference split ignores it
+    want_blocked = n_data == 1 or \
+        resolve_backend(cfg.backend, platform_of(mesh)) == "pallas"
     if cfg.precond == "nystrom" and n_data != 1:
         raise ValueError(
             "precond='nystrom' needs unsharded data axes (its pivot columns "
@@ -279,7 +269,7 @@ def make_krr_step(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def step(x_local, y_local, lsh_local):
-        op = _shard_operator(cfg, f, lsh_local, mesh, fused=local_fused)
+        op = _shard_operator(cfg, f, lsh_local, mesh)
         idx = op.build_index(op.featurize(x_local), blocked=want_blocked)
         mv = make_distributed_matvec(cfg, op, n_data_shards=n_data)
         pre = _shard_preconditioner(cfg, mv, idx)
@@ -295,17 +285,13 @@ def make_krr_step(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
 def make_krr_predict(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     """predict(x_test, lsh, tables) -> yhat; test points data-sharded.
 
-    The index is built with the same ``want_blocked``/``local_fused`` logic
-    as ``make_krr_step`` — a pallas-backend predict gathers through the
-    visit-list kernels off the slot-blocked layout.  A layout-less index
-    would read its loads by a direct row gather (``bin_readout_op``), which
-    needs no slot sort.  Reference-backend prediction skips the layout: its
-    readout never consults it, so the sort would be wasted.
+    A pallas-backend predict builds the slot-blocked layout and gathers
+    through the visit-list kernels.  A layout-less index would read its
+    loads by a direct row gather (``bin_readout_op``), which needs no slot
+    sort.  Reference-backend prediction skips the layout: its readout never
+    consults it, so the sort would be wasted.
     """
-    n_data = _data_shard_count(mesh, cfg)
-    local_fused = cfg.fused and n_data == 1
-    want_blocked = (local_fused or cfg.blocked_split) and \
-        resolve_backend(cfg.backend, platform_of(mesh)) == "pallas"
+    want_blocked = resolve_backend(cfg.backend, platform_of(mesh)) == "pallas"
     in_specs = (P(cfg.data_axes, None),
                 LSHParams(w=P(cfg.model_axis, None), z=P(cfg.model_axis, None),
                           r1=P(cfg.model_axis, None), r2=P(cfg.model_axis, None)),
@@ -315,7 +301,7 @@ def make_krr_predict(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn):
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def predict(x_local, lsh_local, tables_local):
-        op = _shard_operator(cfg, f, lsh_local, mesh, fused=local_fused)
+        op = _shard_operator(cfg, f, lsh_local, mesh)
         idx = op.build_index(op.featurize(x_local), blocked=want_blocked)
         out = op.readout(idx, tables_local, average=False)
         return jax.lax.psum(out, cfg.model_axis) / cfg.m
@@ -863,7 +849,7 @@ def make_krr_step_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
                        out_specs=out_specs)
     def step(x_local, y_local, lsh_local):
         maybe_stall(cfg.fault_plan, cfg.data_axes)
-        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh)
         # blocked=True rides the layout's stable slot sort — the ONLY sort
         # in the step; parts='both' adds the route-kernel arrays on pallas
         idx = op.build_index(op.featurize(x_local), blocked=True,
@@ -989,7 +975,7 @@ def make_krr_predict_hashjoin(mesh: Mesh, cfg: KRRStepConfig, f: BucketFn, *,
     @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs)
     def predict(x_local, lsh_local, table_local):
-        op = _shard_operator(cfg, f, lsh_local, mesh, fused=False)
+        op = _shard_operator(cfg, f, lsh_local, mesh)
         # flatten my (m_loc, spp[, k]) slice to the served id space
         table_flat = table_local.reshape((-1,) + table_local.shape[2:])
         if not dedup:

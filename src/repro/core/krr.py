@@ -314,18 +314,19 @@ def model_operator(model: WLSHKRRModel, *,
 def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
                  m: int, lam: float, mode: str = "table", table_size: int = 0,
                  tol: float = 1e-5, atol: float = 1e-12, maxiter: int = 400,
-                 backend: str | None = "auto", fused: bool = True,
+                 backend: str | None = "auto",
                  precond: str = "none",
                  precond_rank: int = DEFAULT_NYSTROM_RANK,
                  nonfinite_targets: str = "raise",
                  solve_checkpoint_dir: str | None = None,
                  solve_checkpoint_every: int = 0,
                  on_solve_checkpoint=None) -> WLSHKRRModel:
-    """``fused`` selects the one-pass slot-blocked matvec for the CG solve
-    (default); ``fused=False`` keeps the split scatter→gather path reachable
-    for A/B runs.  The fitted model (beta, tables) is identical either way —
-    bitwise on the reference backend.  ``tol``/``atol`` are the PCG relative /
-    absolute residual thresholds (see ``pcg_solve``).
+    """In table mode the index carries the slot-blocked layout, so every
+    CG matvec is the fused one-pass path (``WLSHOperator.matvec``) and the
+    final tables come from the split scatter ``op.loads``; on the reference
+    backend the fused path is bitwise the split readout ∘ loads.
+    ``tol``/``atol`` are the PCG relative / absolute residual thresholds
+    (see ``pcg_solve``).
 
     ``y`` is (n,) for a plain fit or (n, k) for a batched multi-RHS fit
     (k targets — e.g. the GP posterior-sample block from core/gp.py — share
@@ -371,7 +372,7 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
         table_size = default_table_size(n)
     lsh = sample_lsh_params(key, m, d, spec.pdf, spec.lengthscale)
     op = make_operator(lsh, get_bucket_fn(spec.bucket.name), table_size,
-                       backend=backend, fused=fused, platform=platform_of(x))
+                       backend=backend, platform=platform_of(x))
     # the fit.* spans time host work and dispatch (nothing here waits for
     # the device): they name the host's share of a trace's idle gaps
     with obs.span("fit.featurize", {"n": n, "m": m}):
@@ -381,10 +382,10 @@ def wlsh_krr_fit(key: jax.Array, x: Array, y: Array, spec: WLSHKernelSpec, *,
     # out-of-sample points would need a hash join; the signed table is unbiased
     # and O(1) per query — see DESIGN.md §3).  In table mode the same index
     # drives CG, so it is built exactly once (the CG closure closes over the
-    # slot-blocked layout when fused — the sort runs once, not per iteration).
+    # slot-blocked layout — the sort runs once, not per iteration).
     with obs.span("fit.build_index", {"mode": mode}):
         tidx = op.build_index(feats, mode="table",
-                              blocked=fused and mode == "table")
+                              blocked=mode == "table")
         if mode == "exact":
             eidx = op.build_index(feats, mode="exact")
     if mode == "exact":

@@ -7,8 +7,9 @@ the CountSketch table geometry behind a small primitive set:
     build_index     Features -> Table/Exact index (per-point-set structure)
     loads           index, beta -> (m, B) tables  (CountSketch scatter)
     readout         index, tables -> per-point    (CountSketch gather)
-    matvec          index, beta -> K~ beta        (fused one-pass off the
-                    slot-blocked layout, or loads ∘ readout when split)
+    matvec          index, beta -> K~ beta        (fused one-pass when the
+                    index carries the slot-blocked layout, else
+                    readout ∘ loads)
     featurize_buckets    x_query -> TableIndex    (query hash half of predict)
     predict_from_buckets index, tables -> yhat    (readout half of predict —
                          pure function of the query's bucket structure)
@@ -72,7 +73,6 @@ class WLSHOperator(NamedTuple):
     backend: str = "reference"
     interpret: bool = False      # Pallas interpreter, CPU only (ignored by
                                  # reference)
-    fused: bool = True           # one-pass matvec off the slot-blocked layout
 
     # -- featurization ------------------------------------------------------
 
@@ -86,7 +86,7 @@ class WLSHOperator(NamedTuple):
     # -- index construction -------------------------------------------------
 
     def build_index(self, feats: Features, mode: str = "table", *,
-                    blocked: bool | None = None,
+                    blocked: bool = True,
                     parts: str | None = None) -> Index:
         """'table' -> CountSketch TableIndex (both backends); 'exact' ->
         sorted-bucket ExactIndex (reference-only validation path).
@@ -96,9 +96,8 @@ class WLSHOperator(NamedTuple):
         pallas split scatter/gather (``loads``/``readout`` dispatch to the
         visit-list kernels when the layout is present — the distributed
         psum path schedules only real collisions while keeping the
-        (m, B[, k]) tables in HBM).  ``None`` follows the operator's
-        ``fused`` flag.  Readout-only consumers (prediction) pass
-        ``blocked=False`` to skip the sort.  ``parts`` overrides which
+        (m, B[, k]) tables in HBM).  Readout-only consumers (prediction)
+        pass ``blocked=False`` to skip the sort.  ``parts`` overrides which
         layout array group is materialized (default: this backend's own) —
         the hash-join step passes 'both' on the pallas backend because its
         routing build consumes the reference group (perm/segments) while
@@ -106,8 +105,7 @@ class WLSHOperator(NamedTuple):
         """
         if mode == "table":
             idx = build_table_index(feats, self.table_size)
-            want_blocked = self.fused if blocked is None else blocked
-            if want_blocked:
+            if blocked:
                 # only materialize the array group this backend's fused
                 # matvec consumes (the groups are disjoint and O(mn)-sized)
                 idx = idx._replace(blocked=build_blocked_layout(
@@ -124,8 +122,8 @@ class WLSHOperator(NamedTuple):
         """Bucket-load tables for beta — the psum-able object.  (m, B) for a
         (n,) beta; (m, B, k) for a (n, k) RHS block (columns independent).
         On the pallas backend an index carrying the slot-blocked layout
-        scatters through the visit-list kernel (O(n/bn + B/bt) grid) instead
-        of the (n/bn)·(B/bt) cross product — same tables, same psum."""
+        scatters through the visit-list kernel (O(n/bn + B/bt) grid); any
+        other index through ``table_loads`` — same tables, same psum."""
         if self.backend == "pallas":
             from ..kernels.binning import bin_loads_op
             return bin_loads_op(index, beta, interpret=self.interpret)
@@ -153,20 +151,22 @@ class WLSHOperator(NamedTuple):
         solve or batched GP-posterior fit costs far less than k single
         solves (see core/krr.py:pcg_solve).
 
-        Table mode dispatches on the index: with a slot-blocked layout (and
-        ``fused`` set) the scatter and gather run in one pass — a single
-        Pallas kernel whose table tile stays in VMEM, or the reference
-        sorted segment-sum — so the (m, B) table is never materialized
-        between them.  Without a layout it falls back to the split
-        loads → readout composition (the psum-able path).  Exact mode is the
-        reference sorted-bucket estimator (``average`` only).
+        Table mode dispatches on the index alone: when it carries this
+        backend's slot-blocked layout group the scatter and gather run in
+        one pass — a single Pallas kernel whose table tile stays in VMEM, or
+        the reference sorted segment-sum — so the (m, B) table is never
+        materialized between them.  Otherwise it runs the split
+        readout ∘ loads composition.  A caller that must merge the tables
+        between the two halves (the data-sharded psum in core/distributed)
+        calls ``loads`` and ``readout`` itself.  Exact mode is the reference
+        sorted-bucket estimator (``average`` only).
         """
         if isinstance(index, ExactIndex):
             if not average:
                 raise ValueError("exact-mode matvec only supports average=True")
             return exact_matvec(index, beta)
         lay = index.blocked
-        if self.fused and lay is not None:
+        if lay is not None:
             # each backend consumes its own layout group; an index built by
             # the other backend degrades to the split path below
             if self.backend == "pallas" and lay.order is not None:
@@ -225,16 +225,14 @@ class WLSHOperator(NamedTuple):
 def make_operator(lsh: LSHParams, bucket: BucketFn, table_size: int, *,
                   backend: str | None = "auto",
                   interpret: bool | None = None,
-                  fused: bool = True,
                   platform: str | None = None) -> WLSHOperator:
     """Construct an operator with 'auto' backend and interpret mode resolved
     for ``platform`` — the platform the program is placed on (default: the
     default device's).  Everything downstream sees a concrete backend.
     ``interpret=None`` interprets the kernels exactly off the TPU; asking
-    for the interpreter on a TPU raises.  ``fused=False`` keeps the split
-    scatter→gather matvec reachable for A/B runs."""
+    for the interpreter on a TPU raises.  Which table-matvec path runs is
+    the index's to say (``build_index(blocked=...)``), not the operator's."""
     platform = platform_of() if platform is None else platform
     return WLSHOperator(lsh=lsh, bucket=bucket, table_size=int(table_size),
                         backend=resolve_backend(backend, platform),
-                        interpret=resolve_interpret(interpret, platform),
-                        fused=fused)
+                        interpret=resolve_interpret(interpret, platform))

@@ -103,8 +103,7 @@ def exact_kernel_matrix(feats: Features) -> Array:
 # Layout geometry of the visit-list kernels (fused and split alike): one
 # point block of the sorted layout and one table tile.  bn = 128 is the
 # narrowest lane-dense block a TPU kernel accepts and keeps tile-capacity
-# padding small (a nonempty tile wastes at most bn-1 layout slots); bt = 512
-# matches the cross-product kernels.
+# padding small (a nonempty tile wastes at most bn-1 layout slots).
 BLOCKED_N = 128
 BLOCKED_T = 512
 

@@ -14,17 +14,13 @@ run on the VPU in f32: a gather selects exactly one value per point, so it
 is exact, and a scatter adds the same f32 terms as the reference scatter-add
 in a different order.  (As one-hot matmuls these products have 1..k rows,
 so the MXU would spend its time loading the mask as weights, and an f32
-product there takes several bf16 passes.)  Grid iterates the reduction
-dimension (point blocks for scatter, table tiles for gather) in the
-trailing, sequential position so the output tile accumulates in place
-across steps (standard Pallas revisiting pattern).
+product there takes several bf16 passes.)  Every grid walks a visit list
+in its trailing, sequential position, so a table tile visited by
+consecutive steps accumulates in place (standard Pallas revisiting
+pattern).
 
-Two kernel families:
+Two table-matvec kernel families, both driven by the slot-blocked layout:
 
-* **split** (``bin_scatter_pallas`` / ``bin_gather_pallas``) — iterate the
-  full (point-block × table-tile) cross product and materialize the (m, B)
-  table in HBM between the two calls.  O(n·B) mask work, but the table is a
-  psum-able array — this is what the distributed data-shard merge needs.
 * **fused** (``bin_fused_matvec_pallas``) — one ``pallas_call`` drives both
   products off a slot-blocked layout (``core.wlsh.BlockedLayout``): points
   are pre-sorted so each grid visit pairs one point block with the ONE table
@@ -78,9 +74,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-BLOCK_N = 1024       # points per block
-BLOCK_T = 512        # table slots per tile
 
 # Scalar-prefetched schedule bytes per kernel call: half of v5e's 1 MiB SMEM.
 SMEM_SCHEDULE_BYTES = 512 * 1024
@@ -179,48 +172,6 @@ def _grouped_call(body, sched, operands, specs, out_shape, *, name,
             name=name,
         )(*args)
     return out
-
-
-def _scatter_body(slot_ref, contrib_ref, table_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        table_ref[...] = jnp.zeros_like(table_ref)
-
-    hit = _hit(slot_ref, pl.program_id(1), table_ref.shape[-1])
-    table_ref[...] += _cols_to_rows(_scatter(hit, contrib_ref[...]))
-
-
-def _gather_body(slot_ref, table_ref, out_ref):
-    tb = pl.program_id(2)
-
-    @pl.when(tb == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    hit = _hit(slot_ref, tb, table_ref.shape[-1])
-    out_ref[...] += _gather(hit, _rows_to_cols(table_ref[...]))
-
-
-@functools.partial(jax.jit, static_argnames=("table_size", "interpret",
-                                             "block_n", "block_t"))
-def bin_scatter_pallas(slot, contrib, *, table_size: int, interpret: bool,
-                       block_n: int = BLOCK_N, block_t: int = BLOCK_T):
-    """slot (m, n) int32 in [0, table_size); contrib (m, n) f32.
-    Returns tables (m, table_size) f32 with tables[s, j] = sum_{slot==j} contrib."""
-    m, n = slot.shape
-    bn, bt = min(block_n, n), min(block_t, table_size)
-    if n % bn or table_size % bt:
-        raise ValueError("n and table_size must divide their block sizes")
-    point_spec = pl.BlockSpec((None, 1, bn), lambda i, t, j: (i, 0, j))
-    return pl.pallas_call(
-        _scatter_body,
-        grid=(m, table_size // bt, n // bn),
-        in_specs=[point_spec, point_spec],
-        out_specs=pl.BlockSpec((None, 1, bt), lambda i, t, j: (i, 0, t)),
-        out_shape=jax.ShapeDtypeStruct((m, 1, table_size), jnp.float32),
-        interpret=interpret,
-        name="wlsh_table_scatter",
-    )(_with_row_axis(slot), _with_row_axis(contrib.astype(jnp.float32)))[:, 0]
 
 
 def _sorted_blocks(off, block_n, last):
@@ -587,26 +538,3 @@ def route_unpack_pallas(u_block, u_tile, u_flag, cell_lay, coeff_lay, back, *,
         jax.ShapeDtypeStruct((m, k, layout_len), jnp.float32),
         name="wlsh_route_unpack", interpret=interpret)
     return out[:, 0] if k == 1 else out
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_n", "block_t"))
-def bin_gather_pallas(slot, tables, *, interpret: bool,
-                      block_n: int = BLOCK_N, block_t: int = BLOCK_T):
-    """slot (m, n) int32; tables (m, B) f32.  Returns out (m, n) f32 with
-    out[s, i] = tables[s, slot[s, i]]."""
-    m, n = slot.shape
-    table_size = tables.shape[1]
-    bn, bt = min(block_n, n), min(block_t, table_size)
-    if n % bn or table_size % bt:
-        raise ValueError("n and table_size must divide their block sizes")
-    point_spec = pl.BlockSpec((None, 1, bn), lambda i, j, t: (i, 0, j))
-    return pl.pallas_call(
-        _gather_body,
-        grid=(m, n // bn, table_size // bt),
-        in_specs=[point_spec,
-                  pl.BlockSpec((None, 1, bt), lambda i, j, t: (i, 0, t))],
-        out_specs=point_spec,
-        out_shape=jax.ShapeDtypeStruct((m, 1, n), jnp.float32),
-        interpret=interpret,
-        name="wlsh_readout_gather",
-    )(_with_row_axis(slot), _with_row_axis(tables.astype(jnp.float32)))[:, 0]

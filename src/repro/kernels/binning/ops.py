@@ -1,33 +1,33 @@
 """Public ops: CountSketch scatter/readout built on the binning kernels.
 
 These are the kernel-backed equivalents of the reference table primitives in
-``repro.core.wlsh``:
+``repro.core.wlsh``.  Each dispatches on the index alone: an index carrying
+the slot-blocked layout's pallas group takes the visit-list kernels, any
+other index the reference primitive.
 
 * ``bin_loads_op``   ~ ``table_loads``   — scatter signed, weighted beta into
-  the (m, B) CountSketch tables.
+  the (m, B) CountSketch tables (the visit-list scatter, else
+  ``table_loads``'s scatter-add).
 * ``bin_readout_op`` ~ ``table_readout`` — gather every point's bucket load
-  back out and combine over instances (the visit-list kernel when the index
-  carries the slot-blocked layout, else ``table_readout``'s row gather).
-* ``table_matvec_op`` ~ ``table_matvec`` — the composition of the two (the
-  *split* path: the (m, B) table round-trips through HBM between the calls,
-  which is what makes it psum-able in the distributed step).
+  back out and combine over instances (the visit-list gather, else
+  ``table_readout``'s row gather).
 * ``bin_fused_matvec_op`` ~ ``table_matvec_fused`` — one kernel invocation
   driven by the slot-blocked layout (``TableIndex.blocked``): scatter and
   gather share a VMEM-resident table tile, and only O(n/bn + B/bt) visits
-  are scheduled per instance instead of the (n/bn)·(B/bt) cross product.
+  are scheduled per instance.
 
-Shapes are padded internally: ``n`` (points) is padded to the block size with
-an always-zero contribution in slot 0, and ``table_size`` is padded up to a
-multiple of the table tile (padded slots are never addressed, so results are
-exact).  Callers never see padding — outputs are trimmed to logical shapes.
+The two split ops keep the (m, B[, k]) tables in HBM between them, which is
+what makes the distributed data-axis psum possible; the fused op never
+writes them.  Padding (points to whole blocks, table slots to whole tiles)
+is the layout's, and outputs are trimmed to logical shapes.
 ``interpret`` is required: the caller (``core.operator``) decides it from
 the platform the program is placed on — the Pallas interpreter on CPU,
 compiled kernels on TPU.
 
 Each op names its parts in JAX's name stack, as the reference table
 primitives do: the kernel call under ``KERNEL_SCOPE``, the jnp moves around
-it (padding, the gathers into and out of the slot layout, the instance
-mean) under ``LAYOUT_SCOPE``.
+it (the gathers into and out of the slot layout, the instance mean) under
+``LAYOUT_SCOPE``.
 """
 from __future__ import annotations
 
@@ -35,26 +35,13 @@ import jax
 import jax.numpy as jnp
 
 from ...core.wlsh import (KERNEL_SCOPE, LAYOUT_SCOPE, TableIndex,
-                          table_readout)
-from .kernel import (BLOCK_N, BLOCK_T, bin_fused_matvec_pallas,
-                     bin_gather_blocked_pallas, bin_scatter_blocked_pallas,
-                     bin_scatter_pallas)
+                          table_loads, table_readout)
+from .kernel import (bin_fused_matvec_pallas, bin_gather_blocked_pallas,
+                     bin_scatter_blocked_pallas)
 
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
-
-
-def _pad_points(a, bn: int, value=0):
-    n = a.shape[1]
-    return jnp.pad(a, ((0, 0), (0, _round_up(n, bn) - n)),
-                   constant_values=value), n
-
-
-def _block_sizes(n: int, table_size: int, block_n: int, block_t: int):
-    bn = min(block_n, max(128, _round_up(n, 128)))
-    bt = min(block_t, table_size)
-    return bn, bt
 
 
 def _split_layout(index: TableIndex):
@@ -130,36 +117,15 @@ def bin_readout_blocked_op(index: TableIndex, tables, *, interpret: bool,
             else jnp.sum(signed, axis=0)
 
 
-def bin_loads_op(index: TableIndex, beta, *, interpret: bool,
-                 block_n: int = BLOCK_N, block_t: int = BLOCK_T):
+def bin_loads_op(index: TableIndex, beta, *, interpret: bool):
     """Kernel-backed ``table_loads``: (m, B) bucket-load tables for beta (n,),
     or (m, B, k) for a (n, k) RHS block.  An index carrying the slot-blocked
-    layout takes the visit-list kernels (``bin_loads_blocked_op`` — multi-RHS
-    native) at the LAYOUT'S geometry — ``block_n``/``block_t`` here only
-    shape the cross-product fallback (geometry A/B runs rebuild the layout
-    via ``build_blocked_layout``); otherwise the cross-product scatter runs
-    per column — either way the split path stays psum-able."""
+    layout takes the visit-list scatter (``bin_loads_blocked_op``) at the
+    layout's own geometry; otherwise the loads are ``table_loads``'s
+    scatter-add.  Either way the tables are psum-able."""
     if _split_layout(index) is not None:
         return bin_loads_blocked_op(index, beta, interpret=interpret)
-    if beta.ndim == 2:
-        cols = [bin_loads_op(index, beta[:, j], interpret=interpret,
-                             block_n=block_n, block_t=block_t)
-                for j in range(beta.shape[1])]
-        return jnp.stack(cols, axis=-1)
-    bn, bt = _block_sizes(index.slot.shape[1], index.table_size, block_n,
-                          block_t)
-    bp = _round_up(index.table_size, bt)
-    with jax.named_scope(LAYOUT_SCOPE):
-        contrib = (beta[None, :] * index.coeff).astype(jnp.float32)
-        # pad points into slot 0 with zero contribution (cannot perturb loads)
-        slot_p, _ = _pad_points(index.slot, bn, value=0)
-        contrib_p, _ = _pad_points(contrib, bn, value=0.0)
-    with jax.named_scope(KERNEL_SCOPE):
-        tables = bin_scatter_pallas(slot_p, contrib_p, table_size=bp,
-                                    interpret=interpret, block_n=bn,
-                                    block_t=bt)
-    with jax.named_scope(LAYOUT_SCOPE):
-        return tables[:, :index.table_size]
+    return table_loads(index, beta)
 
 
 def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
@@ -177,12 +143,6 @@ def bin_readout_op(index: TableIndex, tables, *, interpret: bool,
         return bin_readout_blocked_op(index, tables, average=average,
                                       interpret=interpret)
     return table_readout(index, tables, average=average)
-
-
-def table_matvec_op(index: TableIndex, beta, *, interpret: bool):
-    """Scatter then gather: the kernel-backed split WLSH table matvec."""
-    tables = bin_loads_op(index, beta, interpret=interpret)
-    return bin_readout_op(index, tables, interpret=interpret)
 
 
 def bin_fused_matvec_op(index: TableIndex, beta, *, interpret: bool,

@@ -124,8 +124,7 @@ def lower_krr_cell(shape_name: str, mesh, variant: str = "psum"):
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     cfg = KRRStepConfig(m=m, table_size=b, lam=KRR_CONFIG.lam,
                         cg_iters=KRR_CONFIG.cg_iters, data_axes=data_axes,
-                        model_axis="model", backend=KRR_CONFIG.backend,
-                        fused=KRR_CONFIG.fused)
+                        model_axis="model", backend=KRR_CONFIG.backend)
     f = get_bucket_fn(KRR_CONFIG.bucket)
     # cap_factor 1.25: at krr_4m the per-destination load is 65536 +- 248
     # (binomial), so 1.25x mean is a +66-sigma overflow margin — free traffic

@@ -59,19 +59,6 @@ def main() -> int:
                     choices=["auto", "reference", "pallas"],
                     help="WLSH operator backend inside each shard "
                          "(auto = pallas on TPU, reference elsewhere)")
-    ap.add_argument("--fused", default=True,
-                    action=argparse.BooleanOptionalAction,
-                    help="one-pass slot-blocked matvec for the CG solve "
-                         "(used when the data axes are unsharded; --no-fused "
-                         "forces the split scatter->gather path for A/B runs)")
-    ap.add_argument("--blocked-split", default=True,
-                    action=argparse.BooleanOptionalAction,
-                    help="visit-list split kernels for the sharded psum "
-                         "path (pallas backend): scatter/gather walk only "
-                         "real (point block, table tile) collisions while "
-                         "the (m, B) tables stay psum-able; "
-                         "--no-blocked-split keeps the cross-product grid "
-                         "for A/B runs")
     ap.add_argument("--precond", default="none",
                     choices=["none", "jacobi", "nystrom"],
                     help="PCG preconditioner (core/precond.py): jacobi works "
@@ -131,7 +118,6 @@ def main() -> int:
     cfg = KRRStepConfig(m=args.m, table_size=table, lam=args.lam,
                         cg_iters=args.cg_iters, data_axes=("data",),
                         model_axis="model", backend=args.backend,
-                        fused=args.fused, blocked_split=args.blocked_split,
                         precond=args.precond,
                         precond_rank=args.precond_rank,
                         overflow=args.overflow)
@@ -182,7 +168,6 @@ def main() -> int:
     print(f"[krr] {args.dataset} scale={args.scale}: n={n_tr} d={d} "
           f"m={args.m} B={table} backend={args.backend} "
           f"({resolve_backend(cfg.backend, platform_of(mesh))}) "
-          f"fused={args.fused} "
           f"precond={args.precond} num_rhs={args.num_rhs} "
           f"table_mode={args.table_mode} wire={args.wire_dtype}")
     dev = mesh.devices.flat[0]

@@ -43,33 +43,64 @@ def test_featurize_parity(n, d, m, table_size):
 def test_tables_and_matvec_parity(n, table_size):
     x, beta, ref, pal = _ops(jax.random.PRNGKey(7 * n), n, 3, 4, table_size)
     fr = ref.featurize(x)
-    idx = ref.build_index(fr)
-    tr, tp = ref.loads(idx, beta), pal.loads(idx, beta)
+    # each backend's own layout group: the pallas index drives the kernels
+    idx, pidx = ref.build_index(fr), pal.build_index(fr)
+    tr, tp = ref.loads(idx, beta), pal.loads(pidx, beta)
     assert tr.shape == tp.shape == (4, table_size)
     np.testing.assert_allclose(tr, tp, atol=1e-4)
-    np.testing.assert_allclose(ref.matvec(idx, beta), pal.matvec(idx, beta),
+    np.testing.assert_allclose(ref.matvec(idx, beta), pal.matvec(pidx, beta),
                                atol=1e-4)
     # sum-mode readout (the distributed path) must agree too
     np.testing.assert_allclose(ref.readout(idx, tr, average=False),
-                               pal.readout(idx, tp, average=False), atol=1e-4)
+                               pal.readout(pidx, tp, average=False),
+                               atol=1e-4)
 
 
 def test_table_tile_padding_path():
-    """table_size not a multiple of the table tile: the kernel pads the table
-    internally and trims — results must match the reference exactly."""
-    from repro.core.wlsh import table_loads, table_readout
+    """table_size not a multiple of the table tile: the layout's tile grid
+    covers the padded table and the kernels trim — results must match the
+    reference exactly."""
+    from repro.core.wlsh import build_blocked_layout, table_loads, \
+        table_readout
     from repro.kernels.binning.ops import bin_loads_op, bin_readout_op
     key = jax.random.PRNGKey(11)
     x, beta, ref, _ = _ops(key, 200, 3, 3, 1024)
-    idx = ref.build_index(ref.featurize(x))
-    # block_t=384 does not divide 1024 -> internal pad to 1152, trim to 1024
-    tk = bin_loads_op(idx, beta, interpret=True, block_t=384)
+    idx = ref.build_index(ref.featurize(x), blocked=False)
+    # block_t=384 does not divide 1024 -> 3 tiles cover 1152, trim to 1024
+    bidx = idx._replace(blocked=build_blocked_layout(
+        idx.slot, idx.coeff, 1024, block_t=384, parts="pallas"))
+    assert bidx.blocked.num_tiles * 384 == 1152
+    tk = bin_loads_op(bidx, beta, interpret=True)
     tr = table_loads(idx, beta)
     assert tk.shape == tr.shape
     np.testing.assert_allclose(tk, tr, atol=1e-4)
     np.testing.assert_allclose(
-        bin_readout_op(idx, tr, interpret=True),
+        bin_readout_op(bidx, tr, interpret=True),
         table_readout(idx, tr), atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [None, 3])
+def test_layoutless_loads_is_table_loads(k):
+    """An index without the slot-blocked layout scatters its loads by
+    ``table_loads``, bitwise, into (m, B) and (m, B, k) tables, at an n off
+    the 128-point block and a table_size off the 512-slot tile; no Pallas
+    kernel is traced."""
+    from repro.core.wlsh import table_loads
+    from repro.kernels.binning.ops import bin_loads_op
+    n, table_size = 200, 256
+    x, beta, _, pal = _ops(jax.random.PRNGKey(16), n, 3, 4, table_size)
+    if k is not None:
+        beta = jax.random.normal(jax.random.PRNGKey(17), (n, k))
+    idx = pal.featurize_buckets(x)
+    assert idx.blocked is None
+
+    def loads(b):
+        return bin_loads_op(idx, b, interpret=True)
+
+    got, want = loads(beta), table_loads(idx, beta)
+    assert got.shape == want.shape == (4, table_size) + beta.shape[1:]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert "pallas_call" not in str(jax.make_jaxpr(loads)(beta))
 
 
 @pytest.mark.parametrize("k", [None, 3])

@@ -91,26 +91,6 @@ def test_lifecycle_no_regression():
     assert not failures, "\n".join(failures)
 
 
-def test_blocked_split_pallas_speedup():
-    """Acceptance pin (PR 5): the visit-list blocked split matvec must beat
-    the cross-product split pallas matvec by >= 3x at n=1024 in interpret
-    mode (the fused kernel got 9-10x from the same slot-sort trick; the
-    split variant keeps the (m, B) table in HBM for the distributed psum,
-    so part of that win is spent on the tile round trips).  Measured fresh —
-    committed trajectory rides BENCH_matvec.json's
-    ``pallas_split_blocked_speedup``."""
-    import jax
-    if jax.default_backend() not in ("cpu", "tpu"):
-        pytest.skip("interpret-mode pin is CPU/TPU only")
-    from benchmarks import bench_matvec
-    rows = bench_matvec.run(ns=(1024,), with_dense=False, with_pcg=False)
-    row = rows[0]
-    assert row["pallas_split_blocked_us"] is not None
-    speedup = row["pallas_us"] / row["pallas_split_blocked_us"]
-    assert speedup >= 3.0, \
-        f"blocked split matvec only {speedup:.2f}x over cross-product split"
-
-
 def test_serving_structural_speedups():
     """Acceptance pins: the warm path must beat the compile-included cold
     first call by >= 5x, and a bucket-exact cache hit must beat the warm

@@ -1,11 +1,11 @@
 """Fused one-pass CountSketch matvec (slot-blocked layout).
 
-Pins the PR's acceptance criteria: parity with the split reference path
-(<= 1e-5, including odd n / non-dividing tile sizes / m=1 / zero weights),
-the O(n) tile-visit schedule (vs the old (n/bn)·(B/bt) cross product), the
-HBM residency claim (the (m, B) table exists in the split program's HLO but
-never in the fused one), bitwise stability of the solver across the fused
-toggle on the reference backend, and the CG atol floor.
+Pins parity with the split reference path (<= 1e-5, including odd n /
+non-dividing tile sizes / m=1 / zero weights), the O(n) tile-visit schedule
+(vs the (n/bn)·(B/bt) cross product), the HBM residency claim (the (m, B)
+table exists in the split program's HLO but never in the fused one),
+bitwise agreement of the fused solve with a split solve on the reference
+backend, and the CG atol floor.
 """
 import jax
 import jax.numpy as jnp
@@ -15,7 +15,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import (GammaPDF, WLSHKernelSpec, cg_solve, get_bucket_fn,
-                        make_operator, sample_lsh_params, wlsh_krr_fit)
+                        make_operator, pcg_solve, sample_lsh_params,
+                        wlsh_krr_fit)
 from repro.core.wlsh import (TableIndex, build_blocked_layout,
                              build_table_index, table_loads, table_matvec,
                              table_matvec_fused, table_readout)
@@ -30,15 +31,14 @@ def _setup(key, n, d, m, table_size, bucket="rect"):
                             GammaPDF(2.0, 1.0))
     beta = jax.random.normal(jax.random.fold_in(key, 2), (n,))
     f = get_bucket_fn(bucket)
-    split = make_operator(lsh, f, table_size, backend="reference", fused=False)
     fused_ref = make_operator(lsh, f, table_size, backend="reference")
     fused_pal = make_operator(lsh, f, table_size, backend="pallas")
-    feats = split.featurize(x)
-    sidx = split.build_index(feats)
+    feats = fused_ref.featurize(x)
+    sidx = build_table_index(feats, table_size)     # split: no layout
     # each backend's build_index materializes only its own layout group
     fidx = fused_ref.build_index(feats)
     fidx_pal = fused_pal.build_index(feats)
-    return beta, split, fused_ref, fused_pal, sidx, fidx, fidx_pal
+    return beta, fused_ref, fused_pal, sidx, fidx, fidx_pal
 
 
 # odd n, n < block_n, m=1, table sizes from one tile up — all padding paths
@@ -48,16 +48,16 @@ def _setup(key, n, d, m, table_size, bucket="rect"):
                                               (257, 3, 3, 2048)])
 def test_fused_matvec_parity(n, d, m, table_size):
     key = jax.random.PRNGKey(n + d + m)
-    beta, split, fused_ref, fused_pal, sidx, fidx, fidx_pal = \
+    beta, fused_ref, fused_pal, sidx, fidx, fidx_pal = \
         _setup(key, n, d, m, table_size)
     assert sidx.blocked is None and fidx.blocked is not None
-    want = split.matvec(sidx, beta)
+    want = table_matvec(sidx, beta)
     got_ref = fused_ref.matvec(fidx, beta)
     got_pal = fused_pal.matvec(fidx_pal, beta)
     np.testing.assert_allclose(got_ref, want, atol=1e-5)
     np.testing.assert_allclose(got_pal, want, atol=1e-5)
     # sum mode (the distributed model-axis contribution) must agree too
-    want_sum = split.matvec(sidx, beta, average=False)
+    want_sum = table_readout(sidx, table_loads(sidx, beta), average=False)
     np.testing.assert_allclose(fused_ref.matvec(fidx, beta, average=False),
                                want_sum, atol=1e-4)
     np.testing.assert_allclose(fused_pal.matvec(fidx_pal, beta, average=False),
@@ -234,7 +234,7 @@ def test_fused_matvec_sorted_read_matches_padded_layout(case, k, average):
 
 def test_blocked_layout_schedules_O_n_tiles():
     """The visit schedule is O(n/bn + B/bt) per instance — linear in n when
-    B = Θ(n) — not the (n/bn)·(B/bt) cross product the split grid iterates."""
+    B = Θ(n) — not the (n/bn)·(B/bt) cross product of a dense grid."""
     key = jax.random.PRNGKey(3)
     n, d, m, table_size = 8192, 4, 4, 32768
     bn, bt = 128, 512
@@ -250,7 +250,7 @@ def test_blocked_layout_schedules_O_n_tiles():
     bound = 2 * (n // bn + n_tiles)          # scatter + gather passes
     assert lay.v_block.shape[1] == bound      # static grid is already O(n)
     assert int(jnp.max(lay.n_visits)) <= bound
-    cross_product = (n // bn) * n_tiles       # split-kernel visits/instance
+    cross_product = (n // bn) * n_tiles       # cross-product grid/instance
     assert bound < cross_product / 4
     # doubling n (with B = 4n) must double the schedule, not quadruple it:
     # build the 2n layout for real and compare static and measured visits
@@ -272,15 +272,12 @@ def test_fused_matvec_table_never_materialized_to_hbm():
     in the fused program (VMEM scratch tile only)."""
     key = jax.random.PRNGKey(7)
     n, d, m, table_size = 300, 3, 4, 1024
-    beta, split, fused_ref, fused_pal, sidx, fidx, fidx_pal = \
+    beta, fused_ref, fused_pal, sidx, fidx, fidx_pal = \
         _setup(key, n, d, m, table_size)
-    pal_split = make_operator(split.lsh, split.bucket, table_size,
-                              backend="pallas", fused=False)
-    for op_split, op_fused, idx in ((split, fused_ref, fidx),
-                                    (pal_split, fused_pal, fidx_pal)):
-        hlo_split = jax.jit(lambda b: op_split.matvec(sidx, b)) \
+    for op, idx in ((fused_ref, fidx), (fused_pal, fidx_pal)):
+        hlo_split = jax.jit(lambda b: op.readout(idx, op.loads(idx, b))) \
             .lower(beta).compile().as_text()
-        hlo_fused = jax.jit(lambda b: op_fused.matvec(idx, b)) \
+        hlo_fused = jax.jit(lambda b: op.matvec(idx, b)) \
             .lower(beta).compile().as_text()
         # the kernels carry the table with a unit row axis, (m, 1, B)
         shapes = ((m, table_size), (m, 1, table_size))
@@ -289,31 +286,44 @@ def test_fused_matvec_table_never_materialized_to_hbm():
 
 
 def test_wlsh_krr_fit_bitwise_stable_across_fused_toggle():
-    """Acceptance criterion: fused vs split solve on the reference backend
-    produces bitwise-identical (beta, tables) — the stable slot sort keeps
-    every bucket's contributions in the same addition order."""
+    """The fit's fused solve on the reference backend produces
+    bitwise-identical (beta, tables) to a split solve — the same LSH, PCG
+    over ``table_matvec`` on a layout-less index, tables by
+    ``table_loads``: the stable slot sort keeps every bucket's
+    contributions in the same addition order."""
     key = jax.random.PRNGKey(0)
-    n, d = 300, 3
+    n, d, m, lam, maxiter = 300, 3, 16, 0.5, 60
     x = jax.random.uniform(key, (n, d)) * 2.0
     y = jax.random.normal(jax.random.fold_in(key, 1), (n,))
     spec = WLSHKernelSpec(bucket=get_bucket_fn("rect"))
-    fit = lambda fused: wlsh_krr_fit(jax.random.fold_in(key, 2), x, y, spec,
-                                     m=16, lam=0.5, maxiter=60,
-                                     backend="reference", fused=fused)
-    m_fused, m_split = fit(True), fit(False)
+    m_fused = wlsh_krr_fit(jax.random.fold_in(key, 2), x, y, spec, m=m,
+                           lam=lam, maxiter=maxiter, backend="reference")
+    lsh = sample_lsh_params(jax.random.fold_in(key, 2), m, d, spec.pdf,
+                            spec.lengthscale)
+    op = make_operator(lsh, spec.bucket, m_fused.table_size,
+                       backend="reference")
+    sidx = build_table_index(op.featurize(x), m_fused.table_size)
+    res = pcg_solve(lambda v: table_matvec(sidx, v), y, lam, tol=1e-5,
+                    maxiter=maxiter)
     np.testing.assert_array_equal(np.asarray(m_fused.beta),
-                                  np.asarray(m_split.beta))
+                                  np.asarray(res.x))
     np.testing.assert_array_equal(np.asarray(m_fused.tables),
-                                  np.asarray(m_split.tables))
-    assert int(m_fused.cg_iters) == int(m_split.cg_iters)
+                                  np.asarray(table_loads(sidx, res.x)))
+    assert int(m_fused.cg_iters) == int(res.col_iters[0])
 
 
 def test_distributed_fused_local_matvec_single_data_shard():
     """Data axes of size 1: make_krr_step takes the fused local-matvec branch
-    (no table psum needed) and must be bitwise-equal to the split step —
-    same guarantee as the single-host fused toggle."""
-    from repro.compat import make_mesh
-    from repro.core.distributed import KRRStepConfig, make_krr_step
+    (no table psum needed) and must be bitwise-equal to a split step on a
+    layout-less index (loads -> data psum -> instance-sum readout -> model
+    psum / m) — same guarantee as the single-host fused solve."""
+    import functools
+
+    from jax.sharding import PartitionSpec as P
+    from repro.compat import make_mesh, shard_map
+    from repro.core.distributed import (KRRStepConfig, _shard_operator,
+                                        cg_iterations, make_krr_step)
+    from repro.core.lsh import LSHParams
     mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     n, d, m, table_size = 192, 3, 4, 512
     key = jax.random.PRNGKey(6)
@@ -322,13 +332,29 @@ def test_distributed_fused_local_matvec_single_data_shard():
     lsh = sample_lsh_params(jax.random.fold_in(key, 2), m, d,
                             GammaPDF(2.0, 1.0))
     f = get_bucket_fn("rect")
-    cfg_fused = KRRStepConfig(m=m, table_size=table_size, lam=0.5,
-                              cg_iters=15, data_axes=("pod", "data"),
-                              model_axis="model", backend="reference",
-                              fused=True)
-    cfg_split = cfg_fused._replace(fused=False)
-    b_f, r_f, t_f = jax.jit(make_krr_step(mesh, cfg_fused, f))(x, y, lsh)
-    b_s, r_s, t_s = jax.jit(make_krr_step(mesh, cfg_split, f))(x, y, lsh)
+    cfg = KRRStepConfig(m=m, table_size=table_size, lam=0.5, cg_iters=15,
+                        data_axes=("pod", "data"), model_axis="model",
+                        backend="reference")
+    data = P(cfg.data_axes)
+    lsh_spec = LSHParams(*(P("model", None),) * 4)
+
+    @functools.partial(shard_map, mesh=mesh,
+                       in_specs=(P(cfg.data_axes, None), data, lsh_spec),
+                       out_specs=(data, P(), P("model", None)))
+    def split_step(x_local, y_local, lsh_local):
+        op = _shard_operator(cfg, f, lsh_local, mesh)
+        idx = op.build_index(op.featurize(x_local), blocked=False)
+
+        def mv(v):
+            tables = jax.lax.psum(op.loads(idx, v), cfg.data_axes)
+            out = op.readout(idx, tables, average=False)
+            return jax.lax.psum(out, cfg.model_axis) / cfg.m
+        beta, resnorm = cg_iterations(mv, y_local, cfg)
+        return beta, resnorm, jax.lax.psum(op.loads(idx, beta),
+                                           cfg.data_axes)
+
+    b_f, r_f, t_f = jax.jit(make_krr_step(mesh, cfg, f))(x, y, lsh)
+    b_s, r_s, t_s = jax.jit(split_step)(x, y, lsh)
     np.testing.assert_array_equal(np.asarray(b_f), np.asarray(b_s))
     np.testing.assert_array_equal(np.asarray(t_f), np.asarray(t_s))
     assert float(r_f) == float(r_s)

@@ -5,11 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GammaPDF, get_bucket_fn, sample_lsh_params
+from repro.core import GammaPDF, get_bucket_fn, make_operator, \
+    sample_lsh_params
 from repro.core.lsh import featurize as featurize_jnp
-from repro.core.wlsh import build_table_index, table_matvec
-from repro.kernels.binning import (bin_gather_pallas, bin_gather_ref,
-                                   bin_scatter_pallas, bin_scatter_ref)
+from repro.core.wlsh import build_blocked_layout, build_table_index, \
+    table_matvec
+from repro.kernels.binning import (bin_gather_blocked_pallas, bin_gather_ref,
+                                   bin_scatter_blocked_pallas,
+                                   bin_scatter_ref)
 from repro.kernels.featurize import featurize_op
 from repro.kernels.flash_decode import flash_decode_pallas, flash_decode_ref
 
@@ -46,35 +49,51 @@ def test_featurize_kernel_f32_input_dtypes():
 
 
 # ---------------------------------------------------------------------------
-# binning (scatter / gather as one-hot MXU matmuls)
+# binning (scatter / gather as one-hot reductions over visit lists)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("m,n,b", [(1, 128, 512), (3, 1024, 1024),
                                    (2, 256, 2048), (2, 1024, 512)])
 def test_bin_scatter_gather_match_ref(m, n, b):
+    """The visit-list scatter and gather over the slot-blocked layout of
+    raw (slot, contrib) arrays match the oracles: contributions enter along
+    the layout (padding positions carry 0), the gather comes back to point
+    order through ``inv_pos``."""
     key = jax.random.PRNGKey(m * n)
     slot = jax.random.randint(key, (m, n), 0, b, dtype=jnp.int32)
     contrib = jax.random.normal(jax.random.fold_in(key, 1), (m, n))
-    t_k = bin_scatter_pallas(slot, contrib, table_size=b, interpret=True,
-                             block_n=min(1024, n), block_t=min(512, b))
+    lay = build_blocked_layout(slot, contrib, b, parts="pallas")
+    padded = jnp.concatenate([contrib, jnp.zeros((m, 1))], axis=1)
+    contrib_lay = jnp.take_along_axis(padded, lay.src, axis=1)
+    t_k = bin_scatter_blocked_pallas(
+        lay.vs_block, lay.vs_tile, lay.slot_lay, contrib_lay,
+        num_tiles=lay.num_tiles, block_n=lay.block_n, block_t=lay.block_t,
+        interpret=True)[:, :b]
     t_r = bin_scatter_ref(slot, contrib, table_size=b)
     np.testing.assert_allclose(t_k, t_r, atol=1e-4)
-    g_k = bin_gather_pallas(slot, t_k, interpret=True,
-                            block_n=min(1024, n), block_t=min(512, b))
+    g_lay = bin_gather_blocked_pallas(lay.vg_tile, lay.slot_lay, t_k,
+                                      block_n=lay.block_n,
+                                      block_t=lay.block_t, interpret=True)
+    g_k = jnp.take_along_axis(g_lay, lay.inv_pos, axis=1)
     np.testing.assert_allclose(g_k, bin_gather_ref(slot, t_r), atol=1e-4)
 
 
 def test_table_matvec_op_matches_core(rng):
-    from repro.kernels.binning.ops import table_matvec_op
+    """A Pallas operator's split readout ∘ loads on a slot-blocked index
+    (the visit-list kernels) is the reference table matvec."""
     n, d, m, b = 300, 3, 6, 1024
     x = jax.random.uniform(rng, (n, d)) * 2.0
     params = sample_lsh_params(jax.random.fold_in(rng, 1), m, d,
                                GammaPDF(2.0, 1.0))
-    feats = featurize_jnp(params, get_bucket_fn("rect"), x)
-    idx = build_table_index(feats, b)
+    f = get_bucket_fn("rect")
+    feats = featurize_jnp(params, f, x)
+    op = make_operator(params, f, b, backend="pallas")
+    bidx = op.build_index(feats)
+    assert bidx.blocked is not None
     beta = jax.random.normal(jax.random.fold_in(rng, 2), (n,))
-    np.testing.assert_allclose(table_matvec_op(idx, beta, interpret=True),
-                               table_matvec(idx, beta), atol=1e-4)
+    np.testing.assert_allclose(op.readout(bidx, op.loads(bidx, beta)),
+                               table_matvec(build_table_index(feats, b), beta),
+                               atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

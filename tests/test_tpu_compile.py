@@ -31,10 +31,8 @@ from repro.core.wlsh import BlockedLayout, TableIndex
 from repro.kernels.binning import (bin_fused_matvec_op,
                                    bin_fused_matvec_pallas,
                                    bin_gather_blocked_pallas,
-                                   bin_gather_pallas,
                                    bin_scatter_blocked_pallas,
-                                   bin_scatter_pallas, route_pack_pallas,
-                                   route_unpack_pallas)
+                                   route_pack_pallas, route_unpack_pallas)
 from repro.kernels.featurize import featurize_pallas
 from repro.serve import Predictor
 from repro.serve.artifact import LoadedArtifact
@@ -163,31 +161,23 @@ def test_fused_matvec_gathers_sorted_order(compile_tpu, k):
     assert M * NB * BN > 2 * M * N
 
 
-def test_blocked_scatter_compiles(compile_tpu):
+@pytest.mark.parametrize("k", [None, 4])
+def test_blocked_scatter_compiles(compile_tpu, k):
+    lay = (M, NB * BN)
+    contrib = lay if k is None else (M, k, NB * BN)
     text = compile_tpu(bin_scatter_blocked_pallas, ((M, NB), I32),
-                       ((M, NB), I32), ((M, NB * BN), I32),
-                       ((M, NB * BN), F32), num_tiles=TILES, block_n=BN,
-                       block_t=BT)
+                       ((M, NB), I32), (lay, I32), (contrib, F32),
+                       num_tiles=TILES, block_n=BN, block_t=BT)
     assert "%wlsh_blocked_scatter" in text
 
 
-def test_blocked_gather_compiles(compile_tpu):
+@pytest.mark.parametrize("k", [None, 4])
+def test_blocked_gather_compiles(compile_tpu, k):
+    tables = (M, B) if k is None else (M, k, B)
     text = compile_tpu(bin_gather_blocked_pallas, ((M, NB), I32),
-                       ((M, NB * BN), I32), ((M, B), F32), block_n=BN,
+                       ((M, NB * BN), I32), (tables, F32), block_n=BN,
                        block_t=BT)
     assert "%wlsh_blocked_gather" in text
-
-
-def test_cross_product_scatter_compiles(compile_tpu):
-    n_pad = -(-N // 1024) * 1024
-    text = compile_tpu(bin_scatter_pallas, ((M, n_pad), I32),
-                       ((M, n_pad), F32), table_size=B)
-    assert "%wlsh_table_scatter" in text
-
-
-def test_cross_product_gather_compiles(compile_tpu):
-    text = compile_tpu(bin_gather_pallas, ((M, 1024), I32), ((M, B), F32))
-    assert "%wlsh_readout_gather" in text
 
 
 def test_serve_readout_is_a_row_gather(topo, one_chip, no_cache):
